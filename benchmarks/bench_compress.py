@@ -70,6 +70,8 @@ def main(smoke: bool = False) -> None:
     env = dict(os.environ)
     env["XLA_FLAGS"] = (f"--xla_force_host_platform_device_count={N_DEVICES} "
                         + env.get("XLA_FLAGS", "")).strip()
+    # a declared CPU simulation: the child never contends for a chip
+    env["JAX_PLATFORMS"] = "cpu"
     env.setdefault("PYTHONPATH", os.path.abspath(
         os.path.join(os.path.dirname(__file__), "..", "src")))
     cmd = [sys.executable, "-m", "benchmarks.bench_compress", "--child"]

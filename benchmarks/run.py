@@ -68,6 +68,7 @@ def main() -> None:
         "roofline": roofline.main,
     }
     print("name,us_per_call,derived")
+    failed = []
     for name, job in jobs.items():
         if args.only and args.only != name:
             continue
@@ -75,6 +76,10 @@ def main() -> None:
             job()
         except Exception as e:  # noqa: BLE001 — keep the suite running
             print(f"{name},0,ERROR:{type(e).__name__}:{e}")
+            failed.append(name)
+    if failed:
+        raise SystemExit(f"{len(failed)} benchmark job(s) failed: "
+                         + ", ".join(failed))
 
 
 if __name__ == "__main__":

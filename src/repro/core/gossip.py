@@ -148,26 +148,23 @@ def lattice_max_degree(graphs) -> int:
                for g in graphs)
 
 
-def stacked_ell_tables(graphs, n_rows: int | None = None):
+def stacked_ell_tables(graphs):
     """Per-run ELL neighbour tables for a topology lattice, stacked.
 
     Every run's neighbour lists are padded to the lattice-wide max degree;
-    padded slots (and rows beyond each graph's n, e.g. sublane padding)
-    point at the row's own index so a weight of 0 makes them exact +0.0
-    contributions.  Shared by the XLA stacked-ELL mix and the batched
-    Pallas kernel wrapper so the two paths can never drift.
+    padded slots point at the row's own index so a weight of 0 makes them
+    exact +0.0 contributions.  Shared by the XLA stacked-ELL mix and the
+    Pallas kernel wrappers so the paths can never drift.
 
     Returns:
-      (nbr, valid, max_deg): nbr (R, n_rows, max(max_deg, 1)) int32 and
+      (nbr, valid, max_deg): nbr (R, n, max(max_deg, 1)) int32 and
       valid (same shape) bool marking real edges.
     """
     n = graphs[0].n
-    if n_rows is None:
-        n_rows = n
     max_deg = max(lattice_max_degree(graphs), 1)
-    nbr = np.tile(np.arange(n_rows, dtype=np.int32)[None, :, None],
+    nbr = np.tile(np.arange(n, dtype=np.int32)[None, :, None],
                   (len(graphs), 1, max_deg))
-    valid = np.zeros((len(graphs), n_rows, max_deg), dtype=bool)
+    valid = np.zeros((len(graphs), n, max_deg), dtype=bool)
     for r, g in enumerate(graphs):
         adj = np.asarray(g.adjacency)
         for i in range(n):
@@ -285,16 +282,9 @@ def make_permute_gossip(graph: topo.Graph, mesh: jax.sharding.Mesh,
             acc = acc + coeff * recv.astype(jnp.float32)
         return acc.astype(x.dtype)
 
-    if hasattr(jax, "shard_map"):  # jax >= 0.5
-        def _shard_map(fn, in_specs, out_specs):
-            return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
-                                 out_specs=out_specs, check_vma=False)
-    else:
-        from jax.experimental.shard_map import shard_map as _sm
-
-        def _shard_map(fn, in_specs, out_specs):
-            return _sm(fn, mesh=mesh, in_specs=in_specs,
-                       out_specs=out_specs, check_rep=False)
+    def _shard_map(fn, in_specs, out_specs):
+        return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                             out_specs=out_specs, check_vma=False)
 
     # One shard-mapped fn per distinct leaf spec, built once at factory time
     # (previously rebuilt per leaf on every gossip() call — pure retracing

@@ -67,20 +67,19 @@ GradFn = Callable[[Any, Any, jax.Array], tuple[jax.Array, Any]]
 LrFn = Callable[[jax.Array], jax.Array]
 
 
-def _shard_map(fn, mesh, in_specs, out_specs, auto=frozenset()):
-    """jax >= 0.5 exposes jax.shard_map; 0.4.x has the experimental one.
+def _shard_map(fn, mesh, in_specs, out_specs, manual=None):
+    """``jax.shard_map`` manual over the ``manual`` mesh axes (all by
+    default).
 
-    ``auto`` names mesh axes left to the GSPMD partitioner (the 2-D engine
-    runs manual over 'agents' with ``auto={'model'}`` so each agent
-    replica's compute is tensor-sharded by the compiler while the gossip /
-    server collectives stay hand-written over the agent axis)."""
-    kw = {"auto": frozenset(auto)} if auto else {}
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False, **kw)
-    from jax.experimental.shard_map import shard_map as _sm
-    return _sm(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=False, **kw)
+    The 2-D engine runs manual over 'agents' only, leaving 'model' to the
+    GSPMD partitioner, so each agent replica's compute is tensor-sharded by
+    the compiler while the gossip / server collectives stay hand-written
+    over the agent axis.  A region nested inside that one passes the
+    context's abstract mesh (``jax.sharding.get_abstract_mesh()``, whose
+    agent axis is already manual) and ``manual={'model'}``."""
+    kw = {} if manual is None else {"axis_names": frozenset(manual)}
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False, **kw)
 
 
 def agent_axis_size(mesh: jax.sharding.Mesh,
@@ -241,7 +240,7 @@ def _blk_mix_for(impl: str, block_d: int | None):
 
 
 def _make_shard_mixer(cfg: FedDecConfig, axis_name, n_shards: int,
-                      block_d: int | None = None, model_axes=None):
+                      block_d: int | None = None, model_ax=None):
     """gossip_impl → per-shard mix(w, x_blk, me) -> y_blk.
 
     ``w`` is the full replicated (n, n) mixing matrix (weights stay random
@@ -249,7 +248,7 @@ def _make_shard_mixer(cfg: FedDecConfig, axis_name, n_shards: int,
     static), ``x_blk`` the shard's (n_local, D) row block, ``me`` the shard
     index on the agent axis.
 
-    ``model_axes=(mesh, model_axis)`` is set by the 2-D lowering: the
+    ``model_ax`` (the model mesh axis) is set by the 2-D lowering: the
     caller's region is manual over the agent axis with the model axis left
     to GSPMD, and gossip commutes with that column sharding (W contracts
     the agent index, elementwise in D — ALGORITHM.md) so the dense
@@ -306,19 +305,24 @@ def _make_shard_mixer(cfg: FedDecConfig, axis_name, n_shards: int,
                                    precision=jax.lax.Precision.HIGHEST)
             return y
 
-        if model_axes is None:
+        if model_ax is None:
             return halo
-        mesh, model_ax = model_axes
-        return _shard_map(halo, mesh,
-                          in_specs=(P(None, None), P(None, model_ax), P()),
-                          out_specs=P(None, model_ax))
+
+        def mix(w, x_blk, me):
+            inner = _shard_map(halo, jax.sharding.get_abstract_mesh(),
+                               in_specs=(P(None, None), P(None, model_ax),
+                                         P()),
+                               out_specs=P(None, model_ax),
+                               manual={model_ax})
+            return inner(w, x_blk, me)
+        return mix
 
     raise engine.unknown_gossip_impl(impl)
 
 
 def _make_compressed_shard_mixer(cfg: FedDecConfig, axis_name, n_shards: int,
                                  compressor, block_d: int | None = None,
-                                 model_axes=None):
+                                 model_ax=None):
     """Compressed-gossip per-shard mixer (repro.core.compress semantics):
 
         mix(w, p_blk, s_blk, payload, me) -> y_blk
@@ -386,9 +390,8 @@ def _make_compressed_shard_mixer(cfg: FedDecConfig, axis_name, n_shards: int,
                                    precision=jax.lax.Precision.HIGHEST)
             return y
 
-        if model_axes is None:
+        if model_ax is None:
             return halo
-        mesh, model_ax = model_axes
 
         def mix(w, p_blk, s_blk, payload, me):
             # encode ran under GSPMD (per-row scales see the full D axis —
@@ -400,10 +403,10 @@ def _make_compressed_shard_mixer(cfg: FedDecConfig, axis_name, n_shards: int,
                 lambda a: P(None, model_ax) if a.ndim == 2 else P(None),
                 payload)
             inner = _shard_map(
-                halo, mesh,
+                halo, jax.sharding.get_abstract_mesh(),
                 in_specs=(P(None, None), P(None, model_ax),
                           P(None, model_ax), pay_specs, P()),
-                out_specs=P(None, model_ax))
+                out_specs=P(None, model_ax), manual={model_ax})
             return inner(w, p_blk, s_blk, payload, me)
         return mix
 
@@ -584,7 +587,7 @@ def _encode_shard_block(compressor, key_c, n_agents: int, n_local: int,
 def _shard_ops(cfg: FedDecConfig, spec: FlatSpec, grad_fn: GradFn,
                lr_fn: LrFn, axis_name, n_shards: int, optimizer,
                block_d: int | None, me_fn=None,
-               model_axes=None) -> engine.EngineOps:
+               model_ax=None) -> engine.EngineOps:
     """The sharded engine's vtable for the shared Algorithm-1 body.
 
     The carry is the per-shard tuple ``(x_blk, res_blk, opt_blk, t)``;
@@ -594,7 +597,7 @@ def _shard_ops(cfg: FedDecConfig, spec: FlatSpec, grad_fn: GradFn,
     ``me_fn`` supplies the shard index on the agent axis; the default is
     ``lax.axis_index``, but the 2-D lowering's partially-auto region cannot
     lower that (the partitioner has no device id under GSPMD) and injects
-    the index from a sharded iota input instead.  ``model_axes`` is
+    the index from a sharded iota input instead.  ``model_ax`` is
     forwarded to the gossip mixers (see :func:`_make_shard_mixer`).
     """
     n_agents = cfg.n_agents
@@ -606,11 +609,11 @@ def _shard_ops(cfg: FedDecConfig, spec: FlatSpec, grad_fn: GradFn,
         if cfg.gossip_impl != "none" else None
     if compressor is None:
         mixer = _make_shard_mixer(cfg, axis_name, n_shards, block_d=block_d,
-                                  model_axes=model_axes)
+                                  model_ax=model_ax)
     else:
         cmixer = _make_compressed_shard_mixer(cfg, axis_name, n_shards,
                                               compressor, block_d=block_d,
-                                              model_axes=model_axes)
+                                              model_ax=model_ax)
 
     def shard_server_round(key, x_blk, me):
         # lines 8–10 as psum + broadcast: every shard draws the same S_t
@@ -686,12 +689,12 @@ def _shard_ops(cfg: FedDecConfig, spec: FlatSpec, grad_fn: GradFn,
 def _build_per_shard_step(cfg: FedDecConfig, spec: FlatSpec, grad_fn: GradFn,
                           lr_fn: LrFn, axis_name, n_shards: int,
                           optimizer, block_d: int | None, me_fn=None,
-                          model_axes=None):
+                          model_ax=None):
     """step(x_blk, res_blk, opt_blk, t, batch_blk, key) over the shared
     body (t advances in the carry; callers thread it)."""
     body = engine.build_step_body(
         _shard_ops(cfg, spec, grad_fn, lr_fn, axis_name, n_shards,
-                   optimizer, block_d, me_fn=me_fn, model_axes=model_axes))
+                   optimizer, block_d, me_fn=me_fn, model_ax=model_ax))
 
     def step(x_blk, res_blk, opt_blk, t, batch_blk, key):
         (z, new_res, new_opt, _), metrics = body(
@@ -754,7 +757,7 @@ def _pin2d(mesh, ax, model_ax, tree):
 def _smap_step_2d(cfg, spec, grad_fn, lr_fn, mesh, ax, n_shards, model_ax,
                   optimizer, block_d):
     """The per-step executor of the 2-D engine: one shard_map, manual over
-    the agent axis, ``auto={model_ax}``.
+    the agent axis, with ``model_ax`` left to GSPMD.
 
     Inside the region every array keeps its logical per-shard shape
     ((n_local, D) blocks) while GSPMD tensor-shards the D dim over
@@ -771,7 +774,7 @@ def _smap_step_2d(cfg, spec, grad_fn, lr_fn, mesh, ax, n_shards, model_ax,
     me_cell = []
     per_shard_body = _build_per_shard_step(
         cfg, spec, grad_fn, lr_fn, ax, n_shards, optimizer, block_d,
-        me_fn=lambda: me_cell[-1], model_axes=(mesh, model_ax))
+        me_fn=lambda: me_cell[-1], model_ax=model_ax)
 
     def per_shard(ids, x_blk, res_blk, opt_blk, t, batch_blk, key_data):
         # the PRNG key crosses the partially-auto boundary as raw u32 data:
@@ -791,7 +794,7 @@ def _smap_step_2d(cfg, spec, grad_fn, lr_fn, mesh, ax, n_shards, model_ax,
         per_shard, mesh,
         in_specs=(P(ax), P(ax), res_specs, opt_specs, P(), P(ax), P()),
         out_specs=(P(ax), res_specs, opt_specs, metric_specs),
-        auto=frozenset({model_ax}))
+        manual={ax})
 
     def call(state: FlatFedState, batch, key):
         ids = jax.lax.with_sharding_constraint(

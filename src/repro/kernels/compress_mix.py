@@ -36,6 +36,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.gossip_mix import HIGHEST
+
 __all__ = ["quant_mix_kernel", "quant_mix_pallas",
            "dequant_mix_kernel", "dequant_mix_pallas"]
 
@@ -51,7 +53,8 @@ def quant_mix_kernel(w_ref, diag_ref, scale_ref, u_ref, noise_ref, p_ref,
                  -127.0, 127.0)
     s = q * scale[:, None]
     p = p_ref[...].astype(jnp.float32)
-    y = jnp.dot(w, s, preferred_element_type=jnp.float32) \
+    y = jnp.dot(w, s, precision=HIGHEST,
+                preferred_element_type=jnp.float32) \
         + diag_ref[...].astype(jnp.float32)[:, None] * (p - s)
     y_ref[...] = y.astype(y_ref.dtype)
     q_ref[...] = q.astype(jnp.int8)
@@ -64,15 +67,12 @@ def quant_mix_pallas(w: jax.Array, diag: jax.Array, scale: jax.Array,
                      interpret: bool = False) -> tuple[jax.Array, jax.Array]:
     """(y, q) = fused stochastic-int8 quantize + mix + EF correction.
 
-    w (n, n), diag = W_ii (n,), scale (n,), u/noise/p (n, D); D must be a
-    multiple of block_d and n a multiple of 8 (ops.quant_mix pads; padded
-    rows must carry scale 1 so the division stays finite).
+    w (n, n), diag = W_ii (n,), scale (n,), u/noise/p (n, D).
     """
     n, d = u.shape
     assert w.shape == (n, n), (w.shape, u.shape)
     assert noise.shape == u.shape == p.shape, (noise.shape, u.shape, p.shape)
-    assert d % block_d == 0, (d, block_d)
-    grid = (d // block_d,)
+    grid = (pl.cdiv(d, block_d),)
     row_spec = pl.BlockSpec((n,), lambda i: (0,))
     tile_spec = pl.BlockSpec((n, block_d), lambda i: (0, i))
     return pl.pallas_call(
@@ -92,7 +92,8 @@ def dequant_mix_kernel(w_ref, diag_ref, scale_ref, q_ref, p_ref, y_ref):
     s = q_ref[...].astype(jnp.float32) \
         * scale_ref[...].astype(jnp.float32)[:, None]
     p = p_ref[...].astype(jnp.float32)
-    y = jnp.dot(w, s, preferred_element_type=jnp.float32) \
+    y = jnp.dot(w, s, precision=HIGHEST,
+                preferred_element_type=jnp.float32) \
         + diag_ref[...].astype(jnp.float32)[:, None] * (p - s)
     y_ref[...] = y.astype(y_ref.dtype)
 
@@ -105,8 +106,7 @@ def dequant_mix_pallas(w: jax.Array, diag: jax.Array, scale: jax.Array,
     """y = W (q·scale) + diag·(p − q·scale), streaming q at 1 B/element."""
     n, d = q.shape
     assert w.shape == (n, n), (w.shape, q.shape)
-    assert d % block_d == 0, (d, block_d)
-    grid = (d // block_d,)
+    grid = (pl.cdiv(d, block_d),)
     row_spec = pl.BlockSpec((n,), lambda i: (0,))
     tile_spec = pl.BlockSpec((n, block_d), lambda i: (0, i))
     return pl.pallas_call(

@@ -1,19 +1,18 @@
 """Public jit'd wrappers for the Pallas kernels.
 
-Handles the host-side plumbing the kernels assume away: CPU fallback to
-``interpret=True`` (this container has no TPU; the kernel body still
-executes, in Python, so tests exercise the real kernel code), shape padding
-to tile boundaries, and pytree-level application for the gossip op.
+Handles the host-side plumbing the kernels assume away: ``interpret=True``
+whenever the backend is not a TPU (the kernel body still executes, in
+Python, so CPU tests exercise the real kernel code), the D tile width, the
+ELL tables of the sparse kernels, and pytree-level application for the
+gossip op.
 """
 
 from __future__ import annotations
 
-import functools
 import os
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
 from repro.kernels import compress_mix as _cm
 from repro.kernels import flash_attention as _fa
@@ -37,24 +36,14 @@ def on_tpu() -> bool:
     return jax.default_backend() == "tpu"
 
 
-@functools.lru_cache(maxsize=None)
-def _interpret_for(backend: str, override: str | None) -> bool:
-    if override is not None:
-        return override.strip().lower() not in ("0", "false", "off",
-                                                "device")
-    return backend != "tpu"
-
-
 def _interpret() -> bool:
-    """Pallas interpret-mode switch, cached per (backend, override).
+    """Pallas interpret mode: exactly when the backend is not a TPU.
 
-    Off-TPU the kernels run under ``interpret=True`` (the kernel body still
-    executes, in Python).  ``REPRO_PALLAS_INTERPRET=1`` forces interpret
-    mode on any backend and ``=0`` forces compiled-device mode — the knob
-    device-vs-interpret differential tests flip.
+    Off-TPU the kernel body still executes, in Python; on the chip every
+    kernel is compiled by Mosaic — nothing can force it back to the
+    interpreter there.
     """
-    return _interpret_for(jax.default_backend(),
-                          os.environ.get("REPRO_PALLAS_INTERPRET"))
+    return not on_tpu()
 
 
 # Measured block_d heuristic (bench_roundfuse.py's block_d sweep): small
@@ -109,8 +98,8 @@ def _clamp_block_d(block_d: int, d: int) -> int:
     """Shrink the D tile to the smallest lane-aligned cover of ``d``.
 
     The 2-D engine hands the kernels (n_local, D/M) sub-blocks of the flat
-    buffer; padding those up to the full 2048-wide tile would multiply the
-    work by orders of magnitude.  The tile stays a multiple of the 128-lane
+    buffer; a full 2048-wide tile over those would compute mostly masked
+    lanes.  The tile stays a multiple of the 128-lane
     width (f32 min tile is (8, 128)) and never grows past the requested
     ``block_d``, so large-D callers are untouched.
     """
@@ -119,18 +108,13 @@ def _clamp_block_d(block_d: int, d: int) -> int:
 
 def gossip_mix(w: jax.Array, x: jax.Array, *,
                block_d: int | None = None):
-    """y = W @ X for (n, D) stacked flats; pads n→8k and D→block_d (the
-    tile autotuned from (D, dtype) when unset, clamped to the lane-aligned
-    cover of D for narrow sub-blocks)."""
-    n, d = x.shape
-    block_d = _resolve_block_d(block_d, d, x.dtype)
-    n_pad = (-n) % 8
-    d_pad = (-d) % block_d
-    wp = jnp.pad(w, ((0, n_pad), (0, n_pad)))
-    xp = jnp.pad(x, ((0, n_pad), (0, d_pad)))
-    y = _gm.gossip_mix_pallas(wp, xp, block_d=block_d,
-                              interpret=_interpret())
-    return y[:n, :d]
+    """y = W @ X for (n, D) stacked flats, with the D tile autotuned from
+    (D, dtype) when unset (clamped to the lane-aligned cover of D for
+    narrow sub-blocks).  No padding copies: row blocks span all n agents
+    and a ragged last D tile is masked by the kernel."""
+    block_d = _resolve_block_d(block_d, x.shape[1], x.dtype)
+    return _gm.gossip_mix_pallas(w, x, block_d=block_d,
+                                 interpret=_interpret())
 
 
 def gossip_mix_batched(w: jax.Array, x: jax.Array, *,
@@ -138,19 +122,12 @@ def gossip_mix_batched(w: jax.Array, x: jax.Array, *,
     """y[r] = W[r] @ X[r] for (R, n, D) stacked run buffers (sweep engine).
 
     One kernel launch for the whole run lattice — grid (R, D/block_d) —
-    instead of R dispatches of the single-run kernel; pads n→8k and
-    D→block_d exactly like :func:`gossip_mix`, so every run's slice is
+    instead of R dispatches of the single-run kernel; every run's slice is
     bit-identical to the single-run kernel's output.
     """
-    r, n, d = x.shape
-    block_d = _resolve_block_d(block_d, d, x.dtype)
-    n_pad = (-n) % 8
-    d_pad = (-d) % block_d
-    wp = jnp.pad(w, ((0, 0), (0, n_pad), (0, n_pad)))
-    xp = jnp.pad(x, ((0, 0), (0, n_pad), (0, d_pad)))
-    y = _gm.gossip_mix_batched_pallas(wp, xp, block_d=block_d,
-                                      interpret=_interpret())
-    return y[:, :n, :d]
+    block_d = _resolve_block_d(block_d, x.shape[2], x.dtype)
+    return _gm.gossip_mix_batched_pallas(w, x, block_d=block_d,
+                                         interpret=_interpret())
 
 
 def gossip_mix_tree(w: jax.Array, stacked) -> object:
@@ -167,44 +144,40 @@ def gossip_mix_tree(w: jax.Array, stacked) -> object:
     return jax.tree.map(mix, stacked)
 
 
+def _ell_tables(graphs):
+    """Host-side ELL neighbour tables of an R-run lattice, closed over by
+    every sparse wrapper: (nbr, valid) (R, n, max_deg), padded slots
+    pointing at the row's own agent (weight 0 at mix time)."""
+    from repro.core import gossip as gossip_lib
+    nbr, valid, _ = gossip_lib.stacked_ell_tables(graphs)
+    return jnp.asarray(nbr), jnp.asarray(valid)
+
+
+def _ell_weights(w, nbr, valid):
+    """Live (wv, wd) edge/diagonal weights from the sampled W — (n, n) with
+    (n, max_deg) tables, or (R, n, n) with (R, n, max_deg) tables."""
+    wf = w.astype(jnp.float32)
+    wv = jnp.where(valid, jnp.take_along_axis(wf, nbr, axis=-1), 0.0)
+    return wv, jnp.diagonal(wf, axis1=-2, axis2=-1)
+
+
 def make_sparse_gossip_pallas(graph, *, block_d: int | None = None):
     """Build the edge-blocked sparse Pallas mix for a static graph.
 
-    Precomputes the ELL neighbour table (n, max_deg) host-side — padded
-    slots point at the row's own agent and get weight 0, and rows added by
-    the n→8k sublane padding are isolated self-loops — then closes over it:
-    ``mix(w, x)`` reads the live edge weights from the sampled (n, n) W, so
-    per-step link failures need no re-indexing.  O(max_deg·n·d) work vs the
-    dense kernel's O(n²·d); same single streaming pass over X.
+    Precomputes the ELL neighbour table (n, max_deg) host-side and closes
+    over it: ``mix(w, x)`` reads the live edge weights from the sampled
+    (n, n) W, so per-step link failures need no re-indexing.
+    O(max_deg·n·d) work vs the dense kernel's O(n²·d); same single
+    streaming pass over X.
     """
-    adj = np.asarray(graph.adjacency)
-    n = adj.shape[0]
-    n_tot = n + ((-n) % 8)
-    max_deg = max(int(adj.sum(axis=1).max()) if n else 0, 1)
-    nbr = np.tile(np.arange(n_tot, dtype=np.int32)[:, None], (1, max_deg))
-    mask = np.zeros((n_tot, max_deg), dtype=bool)
-    for i in range(n):
-        js = np.flatnonzero(adj[i])
-        nbr[i, :len(js)] = js
-        mask[i, :len(js)] = True
-    nbr_j = jnp.asarray(nbr)
-    mask_j = jnp.asarray(mask)
-    row_idx = jnp.asarray(nbr[:n])  # unpadded rows' neighbour columns
+    nbr, valid = (t[0] for t in _ell_tables([graph]))
 
     def mix(w: jax.Array, x: jax.Array) -> jax.Array:
-        assert x.shape[0] == n, (x.shape, n)
-        d = x.shape[1]
-        bd = _resolve_block_d(block_d, d, x.dtype)
-        d_pad = (-d) % bd
-        wf = w.astype(jnp.float32)
-        wv = jnp.zeros((n_tot, max_deg), jnp.float32).at[:n].set(
-            jnp.take_along_axis(wf, row_idx, axis=1))
-        wv = jnp.where(mask_j, wv, 0.0)
-        wd = jnp.zeros((n_tot,), jnp.float32).at[:n].set(jnp.diagonal(wf))
-        xp = jnp.pad(x, ((0, n_tot - n), (0, d_pad)))
-        y = _gm.gossip_mix_sparse_pallas(nbr_j, wv, wd, xp, block_d=bd,
-                                         interpret=_interpret())
-        return y[:n, :d]
+        assert x.shape[0] == graph.n, (x.shape, graph.n)
+        bd = _resolve_block_d(block_d, x.shape[1], x.dtype)
+        wv, wd = _ell_weights(w, nbr, valid)
+        return _gm.gossip_mix_sparse_pallas(nbr, wv, wd, x, block_d=bd,
+                                            interpret=_interpret())
 
     return mix
 
@@ -221,62 +194,16 @@ def make_sparse_gossip_batched_pallas(graphs, *,
     re-indexing.  One kernel launch (grid (R, D/block_d)) covers the whole
     lattice.
     """
-    from repro.core import gossip as gossip_lib
-    n = graphs[0].n
-    r_runs = len(graphs)
-    n_tot = n + ((-n) % 8)
-    nbr, mask, max_deg = gossip_lib.stacked_ell_tables(graphs, n_rows=n_tot)
-    nbr_j = jnp.asarray(nbr)
-    mask_j = jnp.asarray(mask)
-    row_idx = jnp.asarray(nbr[:, :n])  # unpadded rows' neighbour columns
+    nbr, valid = _ell_tables(graphs)
 
     def mix(w: jax.Array, x: jax.Array) -> jax.Array:
-        assert x.shape[:2] == (r_runs, n), (x.shape, r_runs, n)
-        d = x.shape[2]
-        bd = _resolve_block_d(block_d, d, x.dtype)
-        d_pad = (-d) % bd
-        wf = w.astype(jnp.float32)
-        wv = jnp.zeros((r_runs, n_tot, max_deg), jnp.float32).at[:, :n].set(
-            jnp.take_along_axis(wf, row_idx, axis=2))
-        wv = jnp.where(mask_j, wv, 0.0)
-        wd = jnp.zeros((r_runs, n_tot), jnp.float32).at[:, :n].set(
-            jnp.diagonal(wf, axis1=1, axis2=2))
-        xp = jnp.pad(x, ((0, 0), (0, n_tot - n), (0, d_pad)))
-        y = _gm.gossip_mix_sparse_batched_pallas(
-            nbr_j, wv, wd, xp, block_d=bd, interpret=_interpret())
-        return y[:, :n, :d]
+        assert x.shape[:2] == nbr.shape[:2], (x.shape, nbr.shape)
+        bd = _resolve_block_d(block_d, x.shape[2], x.dtype)
+        wv, wd = _ell_weights(w, nbr, valid)
+        return _gm.gossip_mix_sparse_batched_pallas(
+            nbr, wv, wd, x, block_d=bd, interpret=_interpret())
 
     return mix
-
-
-def _ell_table(adj: np.ndarray):
-    """Host-side ELL neighbour table for one adjacency matrix.
-
-    Returns (nbr, mask, n, n_tot, max_deg) with padded slots pointing at
-    the row's own agent (weight 0 at mix time) and the n→8k sublane-padding
-    rows as isolated self-loops — the same layout every sparse kernel
-    assumes.
-    """
-    n = adj.shape[0]
-    n_tot = n + ((-n) % 8)
-    max_deg = max(int(adj.sum(axis=1).max()) if n else 0, 1)
-    nbr = np.tile(np.arange(n_tot, dtype=np.int32)[:, None], (1, max_deg))
-    mask = np.zeros((n_tot, max_deg), dtype=bool)
-    for i in range(n):
-        js = np.flatnonzero(adj[i])
-        nbr[i, :len(js)] = js
-        mask[i, :len(js)] = True
-    return nbr, mask, n, n_tot, max_deg
-
-
-def _ell_weights(w, mask_j, row_idx, n, n_tot, max_deg):
-    """Live (wv, wd) edge/diagonal weights from the sampled (n, n) W."""
-    wf = w.astype(jnp.float32)
-    wv = jnp.zeros((n_tot, max_deg), jnp.float32).at[:n].set(
-        jnp.take_along_axis(wf, row_idx, axis=1))
-    wv = jnp.where(mask_j, wv, 0.0)
-    wd = jnp.zeros((n_tot,), jnp.float32).at[:n].set(jnp.diagonal(wf))
-    return wv, wd
 
 
 # ---------------------------------------------------------------------------
@@ -284,55 +211,61 @@ def _ell_weights(w, mask_j, row_idx, n, n_tot, max_deg):
 # ---------------------------------------------------------------------------
 
 
+def _eta(eta, r=1):
+    return jnp.asarray(eta, jnp.float32).reshape(r, 1)
+
+
 def update_mix(w, x, g, eta, *, m=None, beta=None, nesterov=False,
                block_d: int | None = None):
     """y = W @ (x − η·g) (or the momentum step) in one pass over x/g.
 
-    Pads exactly like :func:`gossip_mix` (padded rows have zero x/g/W, so
-    their update and mixed output are zero and slice off).  Returns y, or
-    (y, new_m) when a momentum buffer ``m`` is passed with ``beta``.
+    Returns y, or (y, new_m) when a momentum buffer ``m`` is passed with
+    ``beta``.
     """
-    n, d = x.shape
-    bd = _resolve_block_d(block_d, d, x.dtype)
-    n_pad = (-n) % 8
-    d_pad = (-d) % bd
-    wp = jnp.pad(w, ((0, n_pad), (0, n_pad)))
-    xp = jnp.pad(x, ((0, n_pad), (0, d_pad)))
-    gp = jnp.pad(g, ((0, n_pad), (0, d_pad)))
-    eta2 = jnp.asarray(eta, jnp.float32).reshape(1, 1)
+    bd = _resolve_block_d(block_d, x.shape[1], x.dtype)
     if m is None:
-        y = _um.update_mix_pallas(wp, xp, gp, eta2, block_d=bd,
-                                  interpret=_interpret())
-        return y[:n, :d]
+        return _um.update_mix_pallas(w, x, g, _eta(eta), block_d=bd,
+                                     interpret=_interpret())
     assert beta is not None, "momentum buffer passed without beta"
-    mp = jnp.pad(m, ((0, n_pad), (0, d_pad)))
-    y, m2 = _um.update_mix_pallas(wp, xp, gp, eta2, mp, beta=beta,
-                                  nesterov=nesterov, block_d=bd,
-                                  interpret=_interpret())
-    return y[:n, :d], m2[:n, :d]
+    return _um.update_mix_pallas(w, x, g, _eta(eta), m, beta=beta,
+                                 nesterov=nesterov, block_d=bd,
+                                 interpret=_interpret())
 
 
 def update_mix_batched(w, x, g, eta, *, m=None, beta=None, nesterov=False,
                        block_d: int | None = None):
     """Batched fused update + mix over (R, n, D) run buffers; eta (R,)."""
-    r, n, d = x.shape
-    bd = _resolve_block_d(block_d, d, x.dtype)
-    n_pad = (-n) % 8
-    d_pad = (-d) % bd
-    wp = jnp.pad(w, ((0, 0), (0, n_pad), (0, n_pad)))
-    xp = jnp.pad(x, ((0, 0), (0, n_pad), (0, d_pad)))
-    gp = jnp.pad(g, ((0, 0), (0, n_pad), (0, d_pad)))
-    eta2 = jnp.asarray(eta, jnp.float32).reshape(r, 1)
+    bd = _resolve_block_d(block_d, x.shape[2], x.dtype)
+    eta2 = _eta(eta, x.shape[0])
     if m is None:
-        y = _um.update_mix_batched_pallas(wp, xp, gp, eta2, block_d=bd,
-                                          interpret=_interpret())
-        return y[:, :n, :d]
+        return _um.update_mix_batched_pallas(w, x, g, eta2, block_d=bd,
+                                             interpret=_interpret())
     assert beta is not None, "momentum buffer passed without beta"
-    mp = jnp.pad(m, ((0, 0), (0, n_pad), (0, d_pad)))
-    y, m2 = _um.update_mix_batched_pallas(wp, xp, gp, eta2, mp, beta=beta,
-                                          nesterov=nesterov, block_d=bd,
-                                          interpret=_interpret())
-    return y[:, :n, :d], m2[:, :n, :d]
+    return _um.update_mix_batched_pallas(w, x, g, eta2, m, beta=beta,
+                                         nesterov=nesterov, block_d=bd,
+                                         interpret=_interpret())
+
+
+def _make_sparse_update_mix(graphs, batched, beta, nesterov, block_d):
+    nbr, valid = _ell_tables(graphs)
+    if not batched:
+        nbr, valid = nbr[0], valid[0]
+    kernel = _um.update_mix_sparse_batched_pallas if batched \
+        else _um.update_mix_sparse_pallas
+
+    def fused(w, x, g, eta, m=None):
+        assert x.shape[:-1] == nbr.shape[:-1], (x.shape, nbr.shape)
+        bd = _resolve_block_d(block_d, x.shape[-1], x.dtype)
+        wv, wd = _ell_weights(w, nbr, valid)
+        eta2 = _eta(eta, x.shape[0] if batched else 1)
+        if m is None:
+            return kernel(nbr, wv, wd, x, g, eta2, block_d=bd,
+                          interpret=_interpret())
+        assert beta is not None, "momentum buffer passed without beta"
+        return kernel(nbr, wv, wd, x, g, eta2, m, beta=beta,
+                      nesterov=nesterov, block_d=bd, interpret=_interpret())
+
+    return fused
 
 
 def make_sparse_update_mix_pallas(graph, *, beta=None, nesterov=False,
@@ -343,79 +276,14 @@ def make_sparse_update_mix_pallas(graph, *, beta=None, nesterov=False,
     ``fused(w, x, g, eta, m=None)`` reads live edge weights from the
     sampled W each step.
     """
-    nbr, mask, n, n_tot, max_deg = _ell_table(np.asarray(graph.adjacency))
-    nbr_j = jnp.asarray(nbr)
-    mask_j = jnp.asarray(mask)
-    row_idx = jnp.asarray(nbr[:n])
-
-    def fused(w, x, g, eta, m=None):
-        assert x.shape[0] == n, (x.shape, n)
-        d = x.shape[1]
-        bd = _resolve_block_d(block_d, d, x.dtype)
-        d_pad = (-d) % bd
-        wv, wd = _ell_weights(w, mask_j, row_idx, n, n_tot, max_deg)
-        xp = jnp.pad(x, ((0, n_tot - n), (0, d_pad)))
-        gp = jnp.pad(g, ((0, n_tot - n), (0, d_pad)))
-        eta2 = jnp.asarray(eta, jnp.float32).reshape(1, 1)
-        if m is None:
-            y = _um.update_mix_sparse_pallas(
-                nbr_j, wv, wd, xp, gp, eta2, block_d=bd,
-                interpret=_interpret())
-            return y[:n, :d]
-        assert beta is not None, "momentum buffer passed without beta"
-        mp = jnp.pad(m, ((0, n_tot - n), (0, d_pad)))
-        y, m2 = _um.update_mix_sparse_pallas(
-            nbr_j, wv, wd, xp, gp, eta2, mp, beta=beta, nesterov=nesterov,
-            block_d=bd, interpret=_interpret())
-        return y[:n, :d], m2[:n, :d]
-
-    return fused
+    return _make_sparse_update_mix([graph], False, beta, nesterov, block_d)
 
 
 def make_sparse_update_mix_batched_pallas(graphs, *, beta=None,
                                           nesterov=False,
                                           block_d: int | None = None):
     """R-run fused update + ELL mix (sweep engine); per-run topologies."""
-    from repro.core import gossip as gossip_lib
-    n = graphs[0].n
-    r_runs = len(graphs)
-    n_tot = n + ((-n) % 8)
-    nbr, mask, max_deg = gossip_lib.stacked_ell_tables(graphs, n_rows=n_tot)
-    nbr_j = jnp.asarray(nbr)
-    mask_j = jnp.asarray(mask)
-    row_idx = jnp.asarray(nbr[:, :n])
-
-    def live_weights(w):
-        wf = w.astype(jnp.float32)
-        wv = jnp.zeros((r_runs, n_tot, max_deg), jnp.float32).at[:, :n].set(
-            jnp.take_along_axis(wf, row_idx, axis=2))
-        wv = jnp.where(mask_j, wv, 0.0)
-        wd = jnp.zeros((r_runs, n_tot), jnp.float32).at[:, :n].set(
-            jnp.diagonal(wf, axis1=1, axis2=2))
-        return wv, wd
-
-    def fused(w, x, g, eta, m=None):
-        assert x.shape[:2] == (r_runs, n), (x.shape, r_runs, n)
-        d = x.shape[2]
-        bd = _resolve_block_d(block_d, d, x.dtype)
-        d_pad = (-d) % bd
-        wv, wd = live_weights(w)
-        xp = jnp.pad(x, ((0, 0), (0, n_tot - n), (0, d_pad)))
-        gp = jnp.pad(g, ((0, 0), (0, n_tot - n), (0, d_pad)))
-        eta2 = jnp.asarray(eta, jnp.float32).reshape(r_runs, 1)
-        if m is None:
-            y = _um.update_mix_sparse_batched_pallas(
-                nbr_j, wv, wd, xp, gp, eta2, block_d=bd,
-                interpret=_interpret())
-            return y[:, :n, :d]
-        assert beta is not None, "momentum buffer passed without beta"
-        mp = jnp.pad(m, ((0, 0), (0, n_tot - n), (0, d_pad)))
-        y, m2 = _um.update_mix_sparse_batched_pallas(
-            nbr_j, wv, wd, xp, gp, eta2, mp, beta=beta, nesterov=nesterov,
-            block_d=bd, interpret=_interpret())
-        return y[:, :n, :d], m2[:, :n, :d]
-
-    return fused
+    return _make_sparse_update_mix(graphs, True, beta, nesterov, block_d)
 
 
 def ef_mix(w, p, s, u, *, block_d: int | None = None):
@@ -424,104 +292,45 @@ def ef_mix(w, p, s, u, *, block_d: int | None = None):
     The encode (whole-row reductions) stays on the shared XLA codec; this
     replaces the mix + correction + residual triple of passes.
     """
-    n, d = p.shape
-    bd = _resolve_block_d(block_d, d, p.dtype)
-    n_pad = (-n) % 8
-    d_pad = (-d) % bd
-    wp = jnp.pad(w, ((0, n_pad), (0, n_pad)))
-    diag = jnp.pad(jnp.diagonal(w), (0, n_pad))
-    pads = ((0, n_pad), (0, d_pad))
-    y, res = _um.ef_mix_pallas(wp, diag, jnp.pad(p, pads),
-                               jnp.pad(s, pads), jnp.pad(u, pads),
-                               block_d=bd, interpret=_interpret())
-    return y[:n, :d], res[:n, :d]
+    bd = _resolve_block_d(block_d, p.shape[1], p.dtype)
+    return _um.ef_mix_pallas(w, jnp.diagonal(w), p, s, u, block_d=bd,
+                             interpret=_interpret())
 
 
 def ef_mix_batched(w, p, s, u, *, block_d: int | None = None):
     """Batched fused EF receive side over (R, n, D) run buffers."""
-    r, n, d = p.shape
-    bd = _resolve_block_d(block_d, d, p.dtype)
-    n_pad = (-n) % 8
-    d_pad = (-d) % bd
-    wp = jnp.pad(w, ((0, 0), (0, n_pad), (0, n_pad)))
-    diag = jnp.pad(jnp.diagonal(w, axis1=1, axis2=2), ((0, 0), (0, n_pad)))
-    pads = ((0, 0), (0, n_pad), (0, d_pad))
-    y, res = _um.ef_mix_batched_pallas(wp, diag, jnp.pad(p, pads),
-                                       jnp.pad(s, pads), jnp.pad(u, pads),
-                                       block_d=bd, interpret=_interpret())
-    return y[:, :n, :d], res[:, :n, :d]
+    bd = _resolve_block_d(block_d, p.shape[2], p.dtype)
+    return _um.ef_mix_batched_pallas(
+        w, jnp.diagonal(w, axis1=1, axis2=2), p, s, u, block_d=bd,
+        interpret=_interpret())
+
+
+def _make_sparse_ef_mix(graphs, batched, block_d):
+    nbr, valid = _ell_tables(graphs)
+    if not batched:
+        nbr, valid = nbr[0], valid[0]
+    kernel = _um.ef_mix_sparse_batched_pallas if batched \
+        else _um.ef_mix_sparse_pallas
+
+    def ef(w, p, s, u):
+        assert p.shape[:-1] == nbr.shape[:-1], (p.shape, nbr.shape)
+        bd = _resolve_block_d(block_d, p.shape[-1], p.dtype)
+        wv, wd = _ell_weights(w, nbr, valid)
+        return kernel(nbr, wv, wd, p, s, u, block_d=bd,
+                      interpret=_interpret())
+
+    return ef
 
 
 def make_sparse_ef_mix_pallas(graph, *, block_d: int | None = None):
     """Sparse fused EF receive side for a static graph: ``ef(w, p, s, u)``."""
-    nbr, mask, n, n_tot, max_deg = _ell_table(np.asarray(graph.adjacency))
-    nbr_j = jnp.asarray(nbr)
-    mask_j = jnp.asarray(mask)
-    row_idx = jnp.asarray(nbr[:n])
-
-    def ef(w, p, s, u):
-        assert p.shape[0] == n, (p.shape, n)
-        d = p.shape[1]
-        bd = _resolve_block_d(block_d, d, p.dtype)
-        d_pad = (-d) % bd
-        wv, wd = _ell_weights(w, mask_j, row_idx, n, n_tot, max_deg)
-        pads = ((0, n_tot - n), (0, d_pad))
-        y, res = _um.ef_mix_sparse_pallas(
-            nbr_j, wv, wd, jnp.pad(p, pads), jnp.pad(s, pads),
-            jnp.pad(u, pads), block_d=bd, interpret=_interpret())
-        return y[:n, :d], res[:n, :d]
-
-    return ef
+    return _make_sparse_ef_mix([graph], False, block_d)
 
 
 def make_sparse_ef_mix_batched_pallas(graphs, *,
                                       block_d: int | None = None):
     """R-run sparse fused EF receive side (sweep engine)."""
-    from repro.core import gossip as gossip_lib
-    n = graphs[0].n
-    r_runs = len(graphs)
-    n_tot = n + ((-n) % 8)
-    nbr, mask, max_deg = gossip_lib.stacked_ell_tables(graphs, n_rows=n_tot)
-    nbr_j = jnp.asarray(nbr)
-    mask_j = jnp.asarray(mask)
-    row_idx = jnp.asarray(nbr[:, :n])
-
-    def ef(w, p, s, u):
-        assert p.shape[:2] == (r_runs, n), (p.shape, r_runs, n)
-        d = p.shape[2]
-        bd = _resolve_block_d(block_d, d, p.dtype)
-        d_pad = (-d) % bd
-        wf = w.astype(jnp.float32)
-        wv = jnp.zeros((r_runs, n_tot, max_deg), jnp.float32).at[:, :n].set(
-            jnp.take_along_axis(wf, row_idx, axis=2))
-        wv = jnp.where(mask_j, wv, 0.0)
-        wd = jnp.zeros((r_runs, n_tot), jnp.float32).at[:, :n].set(
-            jnp.diagonal(wf, axis1=1, axis2=2))
-        pads = ((0, 0), (0, n_tot - n), (0, d_pad))
-        y, res = _um.ef_mix_sparse_batched_pallas(
-            nbr_j, wv, wd, jnp.pad(p, pads), jnp.pad(s, pads),
-            jnp.pad(u, pads), block_d=bd, interpret=_interpret())
-        return y[:, :n, :d], res[:, :n, :d]
-
-    return ef
-
-
-def _pad_compress_args(w, scale, tiles, block_d):
-    """Pad n→8k rows / D→block_d cols for the compress_mix kernels.
-
-    Padded rows are isolated (zero W rows/cols, diag 0) and carry scale 1
-    so the in-kernel ``u / scale`` stays finite; padded columns hold zeros
-    (u=0, noise=0 ⇒ q=0) and are sliced off the outputs.
-    """
-    n, d = tiles[0].shape
-    n_pad = (-n) % 8
-    d_pad = (-d) % block_d
-    wp = jnp.pad(w, ((0, n_pad), (0, n_pad)))
-    diag = jnp.pad(jnp.diagonal(w), (0, n_pad))
-    scale_p = jnp.pad(scale.astype(jnp.float32), (0, n_pad),
-                      constant_values=1.0)
-    padded = [jnp.pad(t, ((0, n_pad), (0, d_pad))) for t in tiles]
-    return wp, diag, scale_p, padded, n, d
+    return _make_sparse_ef_mix(graphs, True, block_d)
 
 
 def quant_mix(w: jax.Array, u: jax.Array, noise: jax.Array, p: jax.Array,
@@ -533,21 +342,20 @@ def quant_mix(w: jax.Array, u: jax.Array, noise: jax.Array, p: jax.Array,
     composing Int8Compressor.encode/decode with the dense mix (the noise
     and scale come from the caller, shared with the XLA path).
     """
-    wp, diag, scale_p, (up, np_, pp), n, d = _pad_compress_args(
-        w, scale, [u, noise, p], block_d)
-    y, q = _cm.quant_mix_pallas(wp, diag, scale_p, up, np_, pp,
+    block_d = _clamp_block_d(block_d, u.shape[1])
+    return _cm.quant_mix_pallas(w, jnp.diagonal(w),
+                                scale.astype(jnp.float32), u, noise, p,
                                 block_d=block_d, interpret=_interpret())
-    return y[:n, :d], q[:n, :d]
 
 
 def dequant_mix(w: jax.Array, q: jax.Array, scale: jax.Array, p: jax.Array,
                 *, block_d: int = _cm.BLOCK_D):
     """Fused int8 dequantize → mix (receive side): streams q at 1 B/elem."""
-    wp, diag, scale_p, (qp, pp), n, d = _pad_compress_args(
-        w, scale, [q, p], block_d)
-    y = _cm.dequant_mix_pallas(wp, diag, scale_p, qp.astype(jnp.int8), pp,
-                               block_d=block_d, interpret=_interpret())
-    return y[:n, :d]
+    block_d = _clamp_block_d(block_d, q.shape[1])
+    return _cm.dequant_mix_pallas(w, jnp.diagonal(w),
+                                  scale.astype(jnp.float32),
+                                  q.astype(jnp.int8), p, block_d=block_d,
+                                  interpret=_interpret())
 
 
 def ssd_scan(x, dt, a, b, c, *, chunk: int = 256):

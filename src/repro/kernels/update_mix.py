@@ -20,7 +20,8 @@ unfused path (core.flat falls back).
 
 Variants (each mirroring its gossip_mix.py counterpart's grid/BlockSpecs):
   * dense        — grid (D/bd,), W (n, n) VMEM-resident;
-  * sparse ELL   — same grid, fori_loop over the (n, max_deg) edge table;
+  * sparse ELL   — same grid, the scalar-prefetched (n, max_deg) edge
+    table walked by kernels.gossip_mix.ell_mix_tile;
   * batched      — leading run axis, grid (R, D/bd) (sweep engine);
   * ef_*         — the codec-active receive side: the update and the
     whole-row encode (int8 scales are full-row reductions — they cannot
@@ -39,6 +40,8 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from repro.kernels.gossip_mix import HIGHEST, ell_call, ell_mix_tile
 
 __all__ = [
     "update_mix_pallas", "update_mix_batched_pallas",
@@ -64,19 +67,7 @@ def _local_step(x, g, m, eta, beta, nesterov):
 
 def _dense_mix(w, p):
     return jnp.dot(w.astype(jnp.float32), p.astype(jnp.float32),
-                   preferred_element_type=jnp.float32)
-
-
-def _ell_mix(nbr, wv, wd, p32):
-    """wd·p + Σ_k wv[:, k]·p[nbr[:, k]] over the static ELL table."""
-    acc = wd.astype(jnp.float32).reshape(-1, 1) * p32
-    max_deg = nbr.shape[1]
-
-    def body(k, acc):
-        coeff = wv[:, k].astype(jnp.float32)
-        return acc + coeff[:, None] * jnp.take(p32, nbr[:, k], axis=0)
-
-    return jax.lax.fori_loop(0, max_deg, body, acc)
+                   precision=HIGHEST, preferred_element_type=jnp.float32)
 
 
 # ---------------------------------------------------------------------------
@@ -107,13 +98,11 @@ def update_mix_pallas(w, x, g, eta, m=None, *, beta=None, nesterov=False,
     """y = W @ (x − η·g) (sgd) or the momentum step; one pass over x/g.
 
     w (n, n), x/g (n, D), eta (1, 1) f32, m (n, D) f32 when ``beta`` is
-    set; D a multiple of block_d, n of 8 (ops.update_mix pads).  Returns y
-    (x.dtype), or (y, new_m) under momentum.
+    set.  Returns y (x.dtype), or (y, new_m) under momentum.
     """
     n, d = x.shape
     assert w.shape == (n, n), (w.shape, x.shape)
-    assert d % block_d == 0, (d, block_d)
-    grid = (d // block_d,)
+    grid = (pl.cdiv(d, block_d),)
     w_spec = pl.BlockSpec((n, n), lambda i: (0, 0))
     nd_spec = pl.BlockSpec((n, block_d), lambda i: (0, i))
     eta_spec = pl.BlockSpec((1, 1), lambda i: (0, 0))
@@ -140,13 +129,13 @@ def _make_dense_batched_kernel(beta, nesterov):
     if beta is None:
         def kernel(w_ref, x_ref, g_ref, eta_ref, y_ref):
             p, _ = _local_step(x_ref[0], g_ref[0], None,
-                               eta_ref[0, 0], None, False)
+                               eta_ref[0], None, False)
             y_ref[0] = _dense_mix(w_ref[0], p).astype(y_ref.dtype)
         return kernel
 
     def kernel(w_ref, x_ref, g_ref, m_ref, eta_ref, y_ref, m_out_ref):
         p, new_m = _local_step(x_ref[0], g_ref[0], m_ref[0],
-                               eta_ref[0, 0], beta, nesterov)
+                               eta_ref[0], beta, nesterov)
         m_out_ref[0] = new_m
         y_ref[0] = _dense_mix(w_ref[0], p).astype(y_ref.dtype)
     return kernel
@@ -161,15 +150,16 @@ def update_mix_batched_pallas(w, x, g, eta, m=None, *, beta=None,
 
     w (R, n, n), x/g (R, n, D), eta (R, 1) f32 (per-run η_t — the sweep
     lattice shares the schedule but the shape keeps the kernel general),
-    m (R, n, D) f32 under momentum.
+    m (R, n, D) f32 under momentum.  Per-run vectors ride as (R, ·, 1)
+    blocks: a block's last two dims must tile (8, 128) or span the array.
     """
     r, n, d = x.shape
     assert w.shape == (r, n, n), (w.shape, x.shape)
-    assert d % block_d == 0, (d, block_d)
-    grid = (r, d // block_d)
+    grid = (r, pl.cdiv(d, block_d))
     w_spec = pl.BlockSpec((1, n, n), lambda r_, i: (r_, 0, 0))
     nd_spec = pl.BlockSpec((1, n, block_d), lambda r_, i: (r_, 0, i))
-    eta_spec = pl.BlockSpec((1, 1), lambda r_, i: (r_, 0))
+    eta_spec = pl.BlockSpec((1, 1, 1), lambda r_, i: (r_, 0, 0))
+    eta = eta.reshape(r, 1, 1)
     kernel = _make_dense_batched_kernel(beta, nesterov)
     if beta is None:
         return pl.pallas_call(
@@ -194,24 +184,36 @@ def update_mix_batched_pallas(w, x, g, eta, m=None, *, beta=None,
 # ---------------------------------------------------------------------------
 
 
-def _make_sparse_kernel(beta, nesterov):
+def _make_sparse_kernel(beta, nesterov, batched):
+    """Fused update + ELL mix; ``batched`` blocks carry a leading run dim
+    (and the run's table offset comes from the grid's first index)."""
+    lead = 0 if batched else ...
+
+    def eta_of(eta_ref):
+        return eta_ref[0] if batched else eta_ref[0, 0]
+
+    def mix(nbr_ref, wv_ref, wd_ref, p, sbuf, gbuf):
+        wv, wd = wv_ref[lead], wd_ref[lead]
+        base = pl.program_id(0) * wv.size if batched else 0
+        return ell_mix_tile(nbr_ref, base, wv, wd, p.astype(jnp.float32),
+                            sbuf, gbuf)
+
     if beta is None:
-        def kernel(nbr_ref, wv_ref, wd_ref, x_ref, g_ref, eta_ref, y_ref):
-            p, _ = _local_step(x_ref[...], g_ref[...], None,
-                               eta_ref[0, 0], None, False)
-            acc = _ell_mix(nbr_ref[...], wv_ref[...], wd_ref[...],
-                           p.astype(jnp.float32))
-            y_ref[...] = acc.astype(y_ref.dtype)
+        def kernel(nbr_ref, wv_ref, wd_ref, x_ref, g_ref, eta_ref, y_ref,
+                   sbuf, gbuf):
+            p, _ = _local_step(x_ref[lead], g_ref[lead], None,
+                               eta_of(eta_ref), None, False)
+            acc = mix(nbr_ref, wv_ref, wd_ref, p, sbuf, gbuf)
+            y_ref[lead] = acc.astype(y_ref.dtype)
         return kernel
 
     def kernel(nbr_ref, wv_ref, wd_ref, x_ref, g_ref, m_ref, eta_ref,
-               y_ref, m_out_ref):
-        p, new_m = _local_step(x_ref[...], g_ref[...], m_ref[...],
-                               eta_ref[0, 0], beta, nesterov)
-        m_out_ref[...] = new_m
-        acc = _ell_mix(nbr_ref[...], wv_ref[...], wd_ref[...],
-                       p.astype(jnp.float32))
-        y_ref[...] = acc.astype(y_ref.dtype)
+               y_ref, m_out_ref, sbuf, gbuf):
+        p, new_m = _local_step(x_ref[lead], g_ref[lead], m_ref[lead],
+                               eta_of(eta_ref), beta, nesterov)
+        m_out_ref[lead] = new_m
+        acc = mix(nbr_ref, wv_ref, wd_ref, p, sbuf, gbuf)
+        y_ref[lead] = acc.astype(y_ref.dtype)
     return kernel
 
 
@@ -226,52 +228,22 @@ def update_mix_sparse_pallas(nbr, wv, wd, x, g, eta, m=None, *, beta=None,
     (g, eta[, m])."""
     n, d = x.shape
     assert nbr.shape == wv.shape and nbr.shape[0] == n, (nbr.shape, x.shape)
-    assert d % block_d == 0, (d, block_d)
-    grid = (d // block_d,)
     ell_spec = pl.BlockSpec((n, nbr.shape[1]), lambda i: (0, 0))
     wd_spec = pl.BlockSpec((n,), lambda i: (0,))
     nd_spec = pl.BlockSpec((n, block_d), lambda i: (0, i))
     eta_spec = pl.BlockSpec((1, 1), lambda i: (0, 0))
-    kernel = _make_sparse_kernel(beta, nesterov)
+    kernel = _make_sparse_kernel(beta, nesterov, batched=False)
+    y_shape = jax.ShapeDtypeStruct((n, d), x.dtype)
     if beta is None:
-        return pl.pallas_call(
-            kernel, grid=grid,
-            in_specs=[ell_spec, ell_spec, wd_spec, nd_spec, nd_spec,
-                      eta_spec],
-            out_specs=nd_spec,
-            out_shape=jax.ShapeDtypeStruct((n, d), x.dtype),
-            interpret=interpret,
-        )(nbr, wv, wd, x, g, eta)
-    return pl.pallas_call(
-        kernel, grid=grid,
-        in_specs=[ell_spec, ell_spec, wd_spec, nd_spec, nd_spec, nd_spec,
-                  eta_spec],
-        out_specs=(nd_spec, nd_spec),
-        out_shape=(jax.ShapeDtypeStruct((n, d), x.dtype),
-                   jax.ShapeDtypeStruct((n, d), jnp.float32)),
-        interpret=interpret,
-    )(nbr, wv, wd, x, g, m, eta)
-
-
-def _make_sparse_batched_kernel(beta, nesterov):
-    if beta is None:
-        def kernel(nbr_ref, wv_ref, wd_ref, x_ref, g_ref, eta_ref, y_ref):
-            p, _ = _local_step(x_ref[0], g_ref[0], None,
-                               eta_ref[0, 0], None, False)
-            acc = _ell_mix(nbr_ref[0], wv_ref[0], wd_ref[0],
-                           p.astype(jnp.float32))
-            y_ref[0] = acc.astype(y_ref.dtype)
-        return kernel
-
-    def kernel(nbr_ref, wv_ref, wd_ref, x_ref, g_ref, m_ref, eta_ref,
-               y_ref, m_out_ref):
-        p, new_m = _local_step(x_ref[0], g_ref[0], m_ref[0],
-                               eta_ref[0, 0], beta, nesterov)
-        m_out_ref[0] = new_m
-        acc = _ell_mix(nbr_ref[0], wv_ref[0], wd_ref[0],
-                       p.astype(jnp.float32))
-        y_ref[0] = acc.astype(y_ref.dtype)
-    return kernel
+        return ell_call(kernel, nbr, n, (pl.cdiv(d, block_d),),
+                        [ell_spec, wd_spec, nd_spec, nd_spec, eta_spec],
+                        nd_spec, y_shape, block_d, interpret)(
+            wv, wd, x, g, eta)
+    return ell_call(kernel, nbr, n, (pl.cdiv(d, block_d),),
+                    [ell_spec, wd_spec, nd_spec, nd_spec, nd_spec, eta_spec],
+                    (nd_spec, nd_spec),
+                    (y_shape, jax.ShapeDtypeStruct((n, d), jnp.float32)),
+                    block_d, interpret)(wv, wd, x, g, m, eta)
 
 
 @functools.partial(jax.jit, static_argnames=("beta", "nesterov", "block_d",
@@ -285,32 +257,25 @@ def update_mix_sparse_batched_pallas(nbr, wv, wd, x, g, eta, m=None, *,
     r, n, d = x.shape
     assert nbr.shape == wv.shape and nbr.shape[:2] == (r, n), \
         (nbr.shape, x.shape)
-    assert d % block_d == 0, (d, block_d)
-    grid = (r, d // block_d)
+    grid = (r, pl.cdiv(d, block_d))
     max_deg = nbr.shape[2]
     ell_spec = pl.BlockSpec((1, n, max_deg), lambda r_, i: (r_, 0, 0))
-    wd_spec = pl.BlockSpec((1, n), lambda r_, i: (r_, 0))
+    wd_spec = pl.BlockSpec((1, n, 1), lambda r_, i: (r_, 0, 0))
     nd_spec = pl.BlockSpec((1, n, block_d), lambda r_, i: (r_, 0, i))
-    eta_spec = pl.BlockSpec((1, 1), lambda r_, i: (r_, 0))
-    kernel = _make_sparse_batched_kernel(beta, nesterov)
+    eta_spec = pl.BlockSpec((1, 1, 1), lambda r_, i: (r_, 0, 0))
+    wd, eta = wd.reshape(r, n, 1), eta.reshape(r, 1, 1)
+    kernel = _make_sparse_kernel(beta, nesterov, batched=True)
+    y_shape = jax.ShapeDtypeStruct((r, n, d), x.dtype)
     if beta is None:
-        return pl.pallas_call(
-            kernel, grid=grid,
-            in_specs=[ell_spec, ell_spec, wd_spec, nd_spec, nd_spec,
-                      eta_spec],
-            out_specs=nd_spec,
-            out_shape=jax.ShapeDtypeStruct((r, n, d), x.dtype),
-            interpret=interpret,
-        )(nbr, wv, wd, x, g, eta)
-    return pl.pallas_call(
-        kernel, grid=grid,
-        in_specs=[ell_spec, ell_spec, wd_spec, nd_spec, nd_spec, nd_spec,
-                  eta_spec],
-        out_specs=(nd_spec, nd_spec),
-        out_shape=(jax.ShapeDtypeStruct((r, n, d), x.dtype),
-                   jax.ShapeDtypeStruct((r, n, d), jnp.float32)),
-        interpret=interpret,
-    )(nbr, wv, wd, x, g, m, eta)
+        return ell_call(kernel, nbr, n, grid,
+                        [ell_spec, wd_spec, nd_spec, nd_spec, eta_spec],
+                        nd_spec, y_shape, block_d, interpret)(
+            wv, wd, x, g, eta)
+    return ell_call(kernel, nbr, n, grid,
+                    [ell_spec, wd_spec, nd_spec, nd_spec, nd_spec, eta_spec],
+                    (nd_spec, nd_spec),
+                    (y_shape, jax.ShapeDtypeStruct((r, n, d), jnp.float32)),
+                    block_d, interpret)(wv, wd, x, g, m, eta)
 
 
 # ---------------------------------------------------------------------------
@@ -337,8 +302,7 @@ def ef_mix_pallas(w, diag, p, s, u, *, block_d: int,
     """
     n, d = p.shape
     assert w.shape == (n, n) and diag.shape == (n,), (w.shape, diag.shape)
-    assert d % block_d == 0, (d, block_d)
-    grid = (d // block_d,)
+    grid = (pl.cdiv(d, block_d),)
     nd_spec = pl.BlockSpec((n, block_d), lambda i: (0, i))
     return pl.pallas_call(
         ef_mix_kernel, grid=grid,
@@ -368,13 +332,13 @@ def ef_mix_batched_pallas(w, diag, p, s, u, *, block_d: int,
     r, n, d = p.shape
     assert w.shape == (r, n, n) and diag.shape == (r, n), \
         (w.shape, diag.shape)
-    assert d % block_d == 0, (d, block_d)
-    grid = (r, d // block_d)
+    grid = (r, pl.cdiv(d, block_d))
     nd_spec = pl.BlockSpec((1, n, block_d), lambda r_, i: (r_, 0, i))
+    diag = diag.reshape(r, n, 1)
     return pl.pallas_call(
         ef_mix_batched_kernel, grid=grid,
         in_specs=[pl.BlockSpec((1, n, n), lambda r_, i: (r_, 0, 0)),
-                  pl.BlockSpec((1, n), lambda r_, i: (r_, 0)),
+                  pl.BlockSpec((1, n, 1), lambda r_, i: (r_, 0, 0)),
                   nd_spec, nd_spec, nd_spec],
         out_specs=(nd_spec, nd_spec),
         out_shape=(jax.ShapeDtypeStruct((r, n, d), p.dtype),
@@ -383,14 +347,20 @@ def ef_mix_batched_pallas(w, diag, p, s, u, *, block_d: int,
     )(w, diag, p, s, u)
 
 
-def ef_mix_sparse_kernel(nbr_ref, wv_ref, wd_ref, p_ref, s_ref, u_ref,
-                         y_ref, r_ref):
-    p, s, u = p_ref[...], s_ref[...], u_ref[...]
-    acc = _ell_mix(nbr_ref[...], wv_ref[...], wd_ref[...],
-                   s.astype(jnp.float32))
-    diag = wd_ref[...].astype(p.dtype).reshape(-1, 1)
-    y_ref[...] = acc.astype(p.dtype) + diag * (p - s)
-    r_ref[...] = u - s
+def _make_ef_sparse_kernel(batched):
+    lead = 0 if batched else ...
+
+    def kernel(nbr_ref, wv_ref, wd_ref, p_ref, s_ref, u_ref, y_ref, r_ref,
+               sbuf, gbuf):
+        p, s, u = p_ref[lead], s_ref[lead], u_ref[lead]
+        wv, wd = wv_ref[lead], wd_ref[lead]
+        base = pl.program_id(0) * wv.size if batched else 0
+        acc = ell_mix_tile(nbr_ref, base, wv, wd, s.astype(jnp.float32),
+                           sbuf, gbuf)
+        diag = wd.astype(p.dtype).reshape(-1, 1)
+        y_ref[lead] = acc.astype(p.dtype) + diag * (p - s)
+        r_ref[lead] = u - s
+    return kernel
 
 
 @functools.partial(jax.jit, static_argnames=("block_d", "interpret"))
@@ -401,28 +371,15 @@ def ef_mix_sparse_pallas(nbr, wv, wd, p, s, u, *, block_d: int,
     kernels."""
     n, d = p.shape
     assert nbr.shape == wv.shape and nbr.shape[0] == n, (nbr.shape, p.shape)
-    assert d % block_d == 0, (d, block_d)
-    grid = (d // block_d,)
     ell_spec = pl.BlockSpec((n, nbr.shape[1]), lambda i: (0, 0))
     nd_spec = pl.BlockSpec((n, block_d), lambda i: (0, i))
-    return pl.pallas_call(
-        ef_mix_sparse_kernel, grid=grid,
-        in_specs=[ell_spec, ell_spec, pl.BlockSpec((n,), lambda i: (0,)),
-                  nd_spec, nd_spec, nd_spec],
-        out_specs=(nd_spec, nd_spec),
-        out_shape=(jax.ShapeDtypeStruct((n, d), p.dtype),
-                   jax.ShapeDtypeStruct((n, d), p.dtype)),
-        interpret=interpret,
-    )(nbr, wv, wd, p, s, u)
-
-
-def ef_mix_sparse_batched_kernel(nbr_ref, wv_ref, wd_ref, p_ref, s_ref,
-                                 u_ref, y_ref, r_ref):
-    p, s, u = p_ref[0], s_ref[0], u_ref[0]
-    acc = _ell_mix(nbr_ref[0], wv_ref[0], wd_ref[0], s.astype(jnp.float32))
-    diag = wd_ref[0].astype(p.dtype).reshape(-1, 1)
-    y_ref[0] = acc.astype(p.dtype) + diag * (p - s)
-    r_ref[0] = u - s
+    out = jax.ShapeDtypeStruct((n, d), p.dtype)
+    return ell_call(_make_ef_sparse_kernel(batched=False), nbr, n,
+                    (pl.cdiv(d, block_d),),
+                    [ell_spec, pl.BlockSpec((n,), lambda i: (0,)),
+                     nd_spec, nd_spec, nd_spec],
+                    (nd_spec, nd_spec), (out, out), block_d, interpret)(
+        wv, wd, p, s, u)
 
 
 @functools.partial(jax.jit, static_argnames=("block_d", "interpret"))
@@ -432,17 +389,14 @@ def ef_mix_sparse_batched_pallas(nbr, wv, wd, p, s, u, *, block_d: int,
     r, n, d = p.shape
     assert nbr.shape == wv.shape and nbr.shape[:2] == (r, n), \
         (nbr.shape, p.shape)
-    assert d % block_d == 0, (d, block_d)
-    grid = (r, d // block_d)
     max_deg = nbr.shape[2]
     ell_spec = pl.BlockSpec((1, n, max_deg), lambda r_, i: (r_, 0, 0))
-    wd_spec = pl.BlockSpec((1, n), lambda r_, i: (r_, 0))
+    wd_spec = pl.BlockSpec((1, n, 1), lambda r_, i: (r_, 0, 0))
     nd_spec = pl.BlockSpec((1, n, block_d), lambda r_, i: (r_, 0, i))
-    return pl.pallas_call(
-        ef_mix_sparse_batched_kernel, grid=grid,
-        in_specs=[ell_spec, ell_spec, wd_spec, nd_spec, nd_spec, nd_spec],
-        out_specs=(nd_spec, nd_spec),
-        out_shape=(jax.ShapeDtypeStruct((r, n, d), p.dtype),
-                   jax.ShapeDtypeStruct((r, n, d), p.dtype)),
-        interpret=interpret,
-    )(nbr, wv, wd, p, s, u)
+    out = jax.ShapeDtypeStruct((r, n, d), p.dtype)
+    wd = wd.reshape(r, n, 1)
+    return ell_call(_make_ef_sparse_kernel(batched=True), nbr, n,
+                    (r, pl.cdiv(d, block_d)),
+                    [ell_spec, wd_spec, nd_spec, nd_spec, nd_spec],
+                    (nd_spec, nd_spec), (out, out), block_d, interpret)(
+        wv, wd, p, s, u)

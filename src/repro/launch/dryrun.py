@@ -195,8 +195,6 @@ def run_one(arch: str, shape_name: str, multi_pod: bool,
 
         mem = compiled.memory_analysis()
         cost = compiled.cost_analysis()
-        if isinstance(cost, list):  # jax < 0.5: one dict per program
-            cost = cost[0] if cost else {}
         hlo = analysis.hlo_analysis.analyze_hlo(compiled.as_text())
         steps_per_call = (fused_steps if fused_steps
                           and shape.kind == "train" else 1)

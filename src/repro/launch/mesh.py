@@ -12,20 +12,28 @@ Mesh shapes (TPU v5e):
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 __all__ = ["make_production_mesh", "make_host_mesh", "make_agent_mesh",
            "make_fed_mesh"]
 
 
+def _auto(n: int) -> tuple[AxisType, ...]:
+    """Auto axis types: GSPMD propagates shardings and
+    ``with_sharding_constraint`` takes plain PartitionSpecs (``jax.make_mesh``
+    defaults to Explicit axes, which carry shardings in the types)."""
+    return (AxisType.Auto,) * n
+
+
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, _auto(len(shape)))
 
 
 def make_host_mesh(data: int = 1, model: int = 1) -> jax.sharding.Mesh:
     """Small mesh over the actually-present devices (tests / examples)."""
-    return jax.make_mesh((data, model), ("data", "model"))
+    return jax.make_mesh((data, model), ("data", "model"), _auto(2))
 
 
 def make_agent_mesh(n_shards: int,
@@ -44,7 +52,7 @@ def make_agent_mesh(n_shards: int,
             f"need 1 <= n_shards <= {avail} available devices, got "
             f"{n_shards} (force host devices with XLA_FLAGS="
             f"--xla_force_host_platform_device_count=N on CPU)")
-    return jax.make_mesh((n_shards,), (axis_name,),
+    return jax.make_mesh((n_shards,), (axis_name,), _auto(1),
                          devices=jax.devices()[:n_shards])
 
 
@@ -78,5 +86,5 @@ def make_fed_mesh(n_agent_shards: int, n_model_shards: int = 1,
             f"CPU)")
     n_dev = n_agent_shards * n_model_shards
     return jax.make_mesh((n_agent_shards, n_model_shards),
-                         (agent_axis, model_axis),
+                         (agent_axis, model_axis), _auto(2),
                          devices=jax.devices()[:n_dev])

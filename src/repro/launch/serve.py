@@ -33,6 +33,7 @@ import jax.numpy as jnp
 
 from repro.checkpoint import load_checkpoint
 from repro.configs import get_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.specs import concrete_batch
 from repro.models import build_model
 
@@ -217,6 +218,7 @@ def main() -> None:
     p.add_argument("--ckpt", default=None,
                    help="checkpoint dir from launch/train.py")
     args = p.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.smoke:

@@ -142,10 +142,7 @@ class Lowerable:
             kw["out_shardings"] = shard(self.out_specs)
         jitted = jax.jit(self.fn, in_shardings=shard(self.in_specs),
                          donate_argnums=self.donate_argnums, **kw)
-        # jax >= 0.5 exposes jax.set_mesh; older versions use the Mesh
-        # object itself as the ambient-mesh context manager
-        mesh_ctx = getattr(jax, "set_mesh", lambda m: m)(mesh)
-        with mesh_ctx:
+        with jax.set_mesh(mesh):
             return jitted.lower(*self.args_struct)
 
 

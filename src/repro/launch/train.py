@@ -34,6 +34,7 @@ from repro.core import sweep as sweep_lib
 from repro.core import topology as topo
 from repro.core.fedavg import FedAvgConfig
 from repro.data.federated_lm import make_federated_lm
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_agent_mesh, make_fed_mesh
 from repro.launch.steps import build_fed_setup, sweep_lattice_configs
 from repro.models import build_model
@@ -553,6 +554,7 @@ def main() -> None:
     p.add_argument("--d-model", type=int, default=768)
     p.add_argument("--layers", type=int, default=12)
     args = p.parse_args()
+    enable_compile_cache()
 
     if args.arch == "tiny":
         cfg = tiny_lm_config(args.d_model, args.layers, vocab=args.vocab)

@@ -174,7 +174,7 @@ pallas = kernel_ops.gossip_mix_tree(w, x)
 perm_fn = gossip.make_permute_gossip(g, mesh, "agents")
 perm_bf16 = gossip.make_permute_gossip(g, mesh, "agents",
                                        exchange_dtype=jnp.bfloat16)
-with getattr(jax, "set_mesh", lambda m: m)(mesh):  # jax<0.5: Mesh is the ctx
+with jax.set_mesh(mesh):
     permuted = jax.jit(perm_fn)(w, x)
     permuted_bf16 = jax.jit(perm_bf16)(w, x)
 for k in x:

@@ -128,7 +128,7 @@ x = {"a": jax.random.normal(jax.random.key(1), (n, 16)),
      "b": jax.random.normal(jax.random.key(2), (n, 4, 4))}
 dense = gossip.gossip_mix_dense(w, x)
 perm_fn = gossip.make_permute_gossip(g, mesh, "agents")
-with getattr(jax, "set_mesh", lambda m: m)(mesh):  # jax<0.5: Mesh is the ctx
+with jax.set_mesh(mesh):
     permuted = jax.jit(perm_fn)(w, x)
 for k in x:
     np.testing.assert_allclose(np.asarray(dense[k]), np.asarray(permuted[k]),

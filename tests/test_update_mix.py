@@ -8,8 +8,8 @@ Four tiers, mirroring tests/test_compress.py's layout:
     nesterov, and the EF ``ef_mix`` family — against the unfused two-pass
     XLA composition, across f32/bf16, non-block_d-aligned D (padding) and
     uneven-degree graphs (ELL degree padding);
-  * the block_d autotune table and its env overrides (REPRO_BLOCK_D,
-    REPRO_PALLAS_INTERPRET);
+  * the block_d autotune table and its REPRO_BLOCK_D override, and the
+    backend rule for interpret mode;
   * engine-level trajectories: ``fuse_update_mix=True`` matches the
     unfused flat/sweep engines to 1e-5 across impls × sgd/momentum ×
     codec on/off; adamw (no fused kernel) falls back bit-identically;
@@ -224,12 +224,16 @@ def test_autotune_block_d_env_override(monkeypatch):
 
 
 def test_interpret_env_override(monkeypatch):
-    monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "0")
-    assert kernel_ops._interpret() is False
-    monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "1")
-    assert kernel_ops._interpret() is True
-    monkeypatch.delenv("REPRO_PALLAS_INTERPRET")
+    """Only the backend decides interpret mode: no environment variable
+    can send a kernel to the interpreter on the chip, or compile it off
+    the chip."""
     assert kernel_ops._interpret() is (jax.default_backend() != "tpu")
+    for env in ("0", "1"):
+        monkeypatch.setenv("REPRO_PALLAS_INTERPRET", env)
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        assert kernel_ops._interpret() is False
+        monkeypatch.setattr(jax, "default_backend", lambda: "cpu")
+        assert kernel_ops._interpret() is True
 
 
 # ---------------------------------------------------------------------------
