@@ -1,0 +1,29 @@
+"""Published peaks of each chip, keyed by JAX's ``device_kind``.
+
+Source: Google Cloud documentation, "TPU v5e" (cloud.google.com/tpu/docs/
+v5e): per chip 197 TFLOP/s bf16, 393 TOP/s int8, 16 GB HBM at 819 GB/s,
+1,600 Gbit/s of inter-chip interconnect.  A device that is not in the
+table is an error, never a default.
+"""
+
+from __future__ import annotations
+
+__all__ = ["PEAKS", "peaks_for"]
+
+PEAKS = {
+    "TPU v5 lite": {
+        "bf16_flops": 197e12,
+        "int8_ops": 393e12,
+        "hbm_bytes": 16e9,
+        "hbm_bytes_per_s": 819e9,
+        "ici_bytes_per_s": 1600e9 / 8,
+    },
+}
+
+
+def peaks_for(device_kind: str) -> dict:
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(f"no published peaks for device kind "
+                       f"{device_kind!r}; known: {sorted(PEAKS)}") from None
