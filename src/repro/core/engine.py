@@ -277,6 +277,13 @@ def build_step_body(ops: EngineOps):
     branch when a codec is configured) → lines 7–12 (server) → carry
     rebuild.  All four engines — and the sharded-sweep composition — run
     exactly this body.
+
+    Each phase runs under a ``feddec.<phase>`` named scope (``sample_w``,
+    ``update``, ``mix``, ``update_mix``, ``server``), which reaches every
+    compiled instruction's ``op_name``; an instruction's phase is the last
+    ``feddec.*`` component of its ``op_name``, so the nested scopes of
+    ``FlatSpec.flatten``/``unflatten`` and ``Model.grad_fn`` name their
+    own ops.  Scopes are metadata: the program is the same without them.
     """
     def step(state, batch, key):
         t = ops.get_step(state)
@@ -287,29 +294,34 @@ def build_step_body(ops: EngineOps):
         eta = ops.eta_fn(t)
 
         # line 3: sample W^t
-        w = ops.sample_w(key_w)
+        with jax.named_scope("feddec.sample_w"):
+            w = ops.sample_w(key_w)
 
         if ops.fused_update_gossip is not None:
             # lines 4–6 in one buffer pass (kernels/update_mix.py)
-            losses, x_next, new_opt, new_res = ops.fused_update_gossip(
-                w, state, batch, key_grad, eta, ops.get_residual(state),
-                key_c)
+            with jax.named_scope("feddec.update_mix"):
+                losses, x_next, new_opt, new_res = ops.fused_update_gossip(
+                    w, state, batch, key_grad, eta, ops.get_residual(state),
+                    key_c)
         else:
             # lines 4–5: per-agent stochastic gradient + local update
-            losses, x_half, new_opt = ops.local_update(state, batch,
-                                                       key_grad, eta)
+            with jax.named_scope("feddec.update"):
+                losses, x_half, new_opt = ops.local_update(state, batch,
+                                                           key_grad, eta)
 
             # line 6: gossip averaging (compressed payload + EF residual
             # when a codec is configured)
-            if ops.ef_gossip is None:
-                x_next = ops.gossip(w, x_half)
-                new_res = ops.get_residual(state)
-            else:
-                x_next, new_res = ops.ef_gossip(
-                    w, x_half, ops.get_residual(state), key_c)
+            with jax.named_scope("feddec.mix"):
+                if ops.ef_gossip is None:
+                    x_next = ops.gossip(w, x_half)
+                    new_res = ops.get_residual(state)
+                else:
+                    x_next, new_res = ops.ef_gossip(
+                        w, x_half, ops.get_residual(state), key_c)
 
         # lines 7–12: periodic server round (partial participation)
-        z_next = ops.server(key_server, x_next, t)
+        with jax.named_scope("feddec.server"):
+            z_next = ops.server(key_server, x_next, t)
 
         return ops.finish(state, z_next, new_opt, new_res, t, losses, eta)
 

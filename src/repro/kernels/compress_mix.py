@@ -84,6 +84,7 @@ def quant_mix_pallas(w: jax.Array, diag: jax.Array, scale: jax.Array,
         out_shape=(jax.ShapeDtypeStruct((n, d), p.dtype),
                    jax.ShapeDtypeStruct((n, d), jnp.int8)),
         interpret=interpret,
+        name="quant_mix",
     )(w, diag, scale, u, noise, p)
 
 
@@ -117,4 +118,5 @@ def dequant_mix_pallas(w: jax.Array, diag: jax.Array, scale: jax.Array,
         out_specs=tile_spec,
         out_shape=jax.ShapeDtypeStruct((n, d), p.dtype),
         interpret=interpret,
+        name="dequant_mix",
     )(w, diag, scale, q, p)

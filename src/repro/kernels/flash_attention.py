@@ -131,5 +131,6 @@ def flash_attention_pallas(q: jax.Array, k: jax.Array, v: jax.Array, *,
                                lambda ib, ik, ig, iq: (ib, ik, ig, iq, 0)),
         out_shape=jax.ShapeDtypeStruct((b, kv, g, s, hd), q.dtype),
         interpret=interpret,
+        name="flash_attention",
     )(qv, kvw, vvw)
     return out.transpose(0, 3, 1, 2, 4).reshape(b, s, h, hd)

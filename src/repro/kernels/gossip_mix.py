@@ -72,6 +72,7 @@ def gossip_mix_pallas(w: jax.Array, x: jax.Array, *, block_d: int = BLOCK_D,
         out_specs=pl.BlockSpec((n, block_d), lambda i: (0, i)),
         out_shape=jax.ShapeDtypeStruct((n, d), x.dtype),
         interpret=interpret,
+        name="gossip_mix",
     )(w, x)
 
 
@@ -117,6 +118,7 @@ def gossip_mix_batched_pallas(w: jax.Array, x: jax.Array, *,
         out_specs=pl.BlockSpec((1, n, block_d), lambda r_, i: (r_, 0, i)),
         out_shape=jax.ShapeDtypeStruct((r, n, d), x.dtype),
         interpret=interpret,
+        name="gossip_mix_batched",
     )(w, x)
 
 
@@ -168,8 +170,8 @@ def ell_mix_tile(nbr_ref, base, wv, wd, src32, sbuf, gbuf):
 
 
 def ell_call(kernel, nbr, n_tab, grid, in_specs, out_specs, out_shape,
-             block_d, interpret, n_scratch=2):
-    """pallas_call with the (…, n, max_deg) neighbour table ``nbr``
+             block_d, interpret, *, name, n_scratch=2):
+    """pallas_call ``name`` with the (…, n, max_deg) neighbour table ``nbr``
     scalar-prefetched (flattened) and ``n_scratch`` (n_tab, block_d) f32
     VMEM buffers; ``in_specs``/``out_specs`` index maps take the grid
     indices only (the prefetched table is appended here)."""
@@ -187,7 +189,8 @@ def ell_call(kernel, nbr, n_tab, grid, in_specs, out_specs, out_shape,
 
     def call(*args):
         return pl.pallas_call(kernel, grid_spec=grid_spec,
-                              out_shape=out_shape, interpret=interpret)(
+                              out_shape=out_shape, interpret=interpret,
+                              name=name)(
             nbr.reshape(-1).astype(jnp.int32), *args)
     return call
 
@@ -221,7 +224,8 @@ def gossip_mix_sparse_pallas(nbr: jax.Array, wv: jax.Array, wd: jax.Array,
                   pl.BlockSpec((n, block_d), lambda i: (0, i))],
         out_specs=pl.BlockSpec((n, block_d), lambda i: (0, i)),
         out_shape=jax.ShapeDtypeStruct((n, d), x.dtype),
-        block_d=block_d, interpret=interpret)(wv, wd, x)
+        block_d=block_d, interpret=interpret,
+        name="gossip_mix_sparse")(wv, wd, x)
 
 
 def gossip_mix_sparse_batched_kernel(nbr_ref, wv_ref, wd_ref, x_ref, y_ref,
@@ -262,4 +266,5 @@ def gossip_mix_sparse_batched_pallas(nbr: jax.Array, wv: jax.Array,
                   pl.BlockSpec((1, n, block_d), lambda r_, i: (r_, 0, i))],
         out_specs=pl.BlockSpec((1, n, block_d), lambda r_, i: (r_, 0, i)),
         out_shape=jax.ShapeDtypeStruct((r, n, d), x.dtype),
-        block_d=block_d, interpret=interpret)(wv, wd.reshape(r, n, 1), x)
+        block_d=block_d, interpret=interpret,
+        name="gossip_mix_sparse_batched")(wv, wd.reshape(r, n, 1), x)

@@ -89,5 +89,6 @@ def rglru_scan_pallas(a: jax.Array, bx: jax.Array, *,
         out_shape=jax.ShapeDtypeStruct((b, s, w), jnp.float32),
         scratch_shapes=[pltpu.VMEM((1, block_w), jnp.float32)],
         interpret=interpret,
+        name="rglru_scan",
     )(a, bx)
     return h, h[:, -1]

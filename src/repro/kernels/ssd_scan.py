@@ -121,5 +121,6 @@ def ssd_scan_pallas(x: jax.Array, dt: jax.Array, a: jax.Array, b: jax.Array,
         out_shape=jax.ShapeDtypeStruct((bs, h, nc, chunk, p), x.dtype),
         scratch_shapes=[pltpu.VMEM((p, n), jnp.float32)],
         interpret=interpret,
+        name="ssd_scan",
     )(xl, la, bb, cc)
     return y.transpose(0, 2, 3, 1, 4).reshape(bs, s, h, p), None

@@ -114,6 +114,7 @@ def update_mix_pallas(w, x, g, eta, m=None, *, beta=None, nesterov=False,
             out_specs=nd_spec,
             out_shape=jax.ShapeDtypeStruct((n, d), x.dtype),
             interpret=interpret,
+            name="update_mix",
         )(w, x, g, eta)
     return pl.pallas_call(
         kernel, grid=grid,
@@ -122,6 +123,7 @@ def update_mix_pallas(w, x, g, eta, m=None, *, beta=None, nesterov=False,
         out_shape=(jax.ShapeDtypeStruct((n, d), x.dtype),
                    jax.ShapeDtypeStruct((n, d), jnp.float32)),
         interpret=interpret,
+        name="update_mix",
     )(w, x, g, m, eta)
 
 
@@ -168,6 +170,7 @@ def update_mix_batched_pallas(w, x, g, eta, m=None, *, beta=None,
             out_specs=nd_spec,
             out_shape=jax.ShapeDtypeStruct((r, n, d), x.dtype),
             interpret=interpret,
+            name="update_mix_batched",
         )(w, x, g, eta)
     return pl.pallas_call(
         kernel, grid=grid,
@@ -176,6 +179,7 @@ def update_mix_batched_pallas(w, x, g, eta, m=None, *, beta=None,
         out_shape=(jax.ShapeDtypeStruct((r, n, d), x.dtype),
                    jax.ShapeDtypeStruct((r, n, d), jnp.float32)),
         interpret=interpret,
+        name="update_mix_batched",
     )(w, x, g, m, eta)
 
 
@@ -237,13 +241,15 @@ def update_mix_sparse_pallas(nbr, wv, wd, x, g, eta, m=None, *, beta=None,
     if beta is None:
         return ell_call(kernel, nbr, n, (pl.cdiv(d, block_d),),
                         [ell_spec, wd_spec, nd_spec, nd_spec, eta_spec],
-                        nd_spec, y_shape, block_d, interpret)(
+                        nd_spec, y_shape, block_d, interpret,
+                        name="update_mix_sparse")(
             wv, wd, x, g, eta)
     return ell_call(kernel, nbr, n, (pl.cdiv(d, block_d),),
                     [ell_spec, wd_spec, nd_spec, nd_spec, nd_spec, eta_spec],
                     (nd_spec, nd_spec),
                     (y_shape, jax.ShapeDtypeStruct((n, d), jnp.float32)),
-                    block_d, interpret)(wv, wd, x, g, m, eta)
+                    block_d, interpret, name="update_mix_sparse")(
+        wv, wd, x, g, m, eta)
 
 
 @functools.partial(jax.jit, static_argnames=("beta", "nesterov", "block_d",
@@ -269,13 +275,15 @@ def update_mix_sparse_batched_pallas(nbr, wv, wd, x, g, eta, m=None, *,
     if beta is None:
         return ell_call(kernel, nbr, n, grid,
                         [ell_spec, wd_spec, nd_spec, nd_spec, eta_spec],
-                        nd_spec, y_shape, block_d, interpret)(
+                        nd_spec, y_shape, block_d, interpret,
+                        name="update_mix_sparse_batched")(
             wv, wd, x, g, eta)
     return ell_call(kernel, nbr, n, grid,
                     [ell_spec, wd_spec, nd_spec, nd_spec, nd_spec, eta_spec],
                     (nd_spec, nd_spec),
                     (y_shape, jax.ShapeDtypeStruct((r, n, d), jnp.float32)),
-                    block_d, interpret)(wv, wd, x, g, m, eta)
+                    block_d, interpret, name="update_mix_sparse_batched")(
+        wv, wd, x, g, m, eta)
 
 
 # ---------------------------------------------------------------------------
@@ -313,6 +321,7 @@ def ef_mix_pallas(w, diag, p, s, u, *, block_d: int,
         out_shape=(jax.ShapeDtypeStruct((n, d), p.dtype),
                    jax.ShapeDtypeStruct((n, d), p.dtype)),
         interpret=interpret,
+        name="ef_mix",
     )(w, diag, p, s, u)
 
 
@@ -344,6 +353,7 @@ def ef_mix_batched_pallas(w, diag, p, s, u, *, block_d: int,
         out_shape=(jax.ShapeDtypeStruct((r, n, d), p.dtype),
                    jax.ShapeDtypeStruct((r, n, d), p.dtype)),
         interpret=interpret,
+        name="ef_mix_batched",
     )(w, diag, p, s, u)
 
 
@@ -378,7 +388,8 @@ def ef_mix_sparse_pallas(nbr, wv, wd, p, s, u, *, block_d: int,
                     (pl.cdiv(d, block_d),),
                     [ell_spec, pl.BlockSpec((n,), lambda i: (0,)),
                      nd_spec, nd_spec, nd_spec],
-                    (nd_spec, nd_spec), (out, out), block_d, interpret)(
+                    (nd_spec, nd_spec), (out, out), block_d, interpret,
+                    name="ef_mix_sparse")(
         wv, wd, p, s, u)
 
 
@@ -398,5 +409,6 @@ def ef_mix_sparse_batched_pallas(nbr, wv, wd, p, s, u, *, block_d: int,
     return ell_call(_make_ef_sparse_kernel(batched=True), nbr, n,
                     (r, pl.cdiv(d, block_d)),
                     [ell_spec, wd_spec, nd_spec, nd_spec, nd_spec],
-                    (nd_spec, nd_spec), (out, out), block_d, interpret)(
+                    (nd_spec, nd_spec), (out, out), block_d, interpret,
+                    name="ef_mix_sparse_batched")(
         wv, wd, p, s, u)

@@ -20,6 +20,7 @@ import time
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import StepTraceAnnotation, TraceAnnotation
 
 from repro import optim
 from repro.checkpoint import save_checkpoint
@@ -114,6 +115,14 @@ def train_loop(cfg: ArchConfig, fed: FedConfig, *, steps: int,
     scan runs inside one shard_map, so the per-step collectives are the
     only cross-device traffic of the entire figure lattice.  Every run
     slice matches the single-run flat engine to ≤ 1e-5.
+
+    Tracing: ``with jax.profiler.trace(log_dir): train_loop(...)`` records
+    each fused round as a ``feddec.round`` step (``StepTraceAnnotation``,
+    ``step_num`` = the round), with the host spans ``feddec.sample`` (the
+    token draw), ``feddec.loss_pull`` (the wait for the round's losses) and
+    ``feddec.ckpt`` inside it; the device ops carry the engine's
+    ``feddec.*`` phase scopes in their ``op_name``.  The logged steps/s
+    counts from the end of the first round, which compiles.
     """
     model = build_model(cfg)
     axes = MeshAxes(("data",), "model", {"data": fed.n_agents, "model": 1})
@@ -280,56 +289,75 @@ def train_loop(cfg: ArchConfig, fed: FedConfig, *, steps: int,
             jnp.arange(sweep_runs)) if sweep_axis == "seed" else \
             jnp.broadcast_to(step_key[None], (sweep_runs,))
     losses = []
-    t_start = time.time()
+    # steps done and clock at the end of the first round (or step), which
+    # compiles: the logged rate counts from there
+    first = {}
 
     def log_and_ckpt(prev: int, done: int) -> None:
+        if not first:
+            first.update(done=done, t=time.perf_counter())
         # fire when a multiple of the period falls in (prev, done] — a fused
         # round advances h steps at once and must not skip boundaries
         if log_every and done // log_every > prev // log_every:
-            rate = done / (time.time() - t_start)
+            since = done - first["done"]
+            rate = (f"{since / (time.perf_counter() - first['t']):.2f} "
+                    f"steps/s" if since else "first round, compiled")
             print(f"[train] step {done:5d}  loss {losses[-1]:.4f}  "
-                  f"({rate:.2f} steps/s)")
+                  f"({rate})")
         if (ckpt_dir and ckpt_every
                 and done // ckpt_every > prev // ckpt_every):
-            save_checkpoint(ckpt_dir, done,
-                            {"params": ckpt_params(state),
-                             "step": state.step})
+            with TraceAnnotation("feddec.ckpt"):
+                save_checkpoint(ckpt_dir, done,
+                                {"params": ckpt_params(state),
+                                 "step": state.step})
 
     if fused:
         done = 0
         while done < steps:
-            chunk = min(fed.h, steps - done)
-            key, kd = jax.random.split(key)
-            tokens = jax.vmap(lambda k: data.sample(k, per_agent_batch))(
-                jax.random.split(kd, chunk))
-            batches = {"tokens": tokens,
-                       "positions": jnp.broadcast_to(
-                           positions[None], (chunk,) + positions.shape)}
-            if sweep_runs is not None:
-                # shared data stream, one (chunk, R, ...) lattice round
-                batches = jax.tree.map(
-                    lambda b: jnp.broadcast_to(
-                        b[:, None], (b.shape[0], sweep_runs) + b.shape[1:]),
-                    batches)
-                state, metrics = round_fn(state, batches, run_keys)
-                losses.extend(
-                    np.asarray(metrics["loss"].mean(axis=1)).tolist())
-            else:
-                state, metrics = round_fn(state, batches, step_key)
-                losses.extend(np.asarray(metrics["loss"]).tolist())
-            done += chunk
-            log_and_ckpt(done - chunk, done)
+            with StepTraceAnnotation("feddec.round",
+                                     step_num=done // fed.h):
+                chunk = min(fed.h, steps - done)
+                with TraceAnnotation("feddec.sample"):
+                    key, kd = jax.random.split(key)
+                    tokens = jax.vmap(
+                        lambda k: data.sample(k, per_agent_batch))(
+                        jax.random.split(kd, chunk))
+                    batches = {"tokens": tokens,
+                               "positions": jnp.broadcast_to(
+                                   positions[None],
+                                   (chunk,) + positions.shape)}
+                    if sweep_runs is not None:
+                        # shared data stream, one (chunk, R, ...) round
+                        batches = jax.tree.map(
+                            lambda b: jnp.broadcast_to(
+                                b[:, None],
+                                (b.shape[0], sweep_runs) + b.shape[1:]),
+                            batches)
+                state, metrics = round_fn(
+                    state, batches,
+                    step_key if sweep_runs is None else run_keys)
+                with TraceAnnotation("feddec.loss_pull"):
+                    loss = metrics["loss"]
+                    if sweep_runs is not None:
+                        loss = loss.mean(axis=1)  # the lattice's mean
+                    losses.extend(np.asarray(loss).tolist())
+                done += chunk
+                log_and_ckpt(done - chunk, done)
     else:
         for i in range(steps):
-            key, kd = jax.random.split(key)
-            tokens = data.sample(kd, per_agent_batch)
+            with TraceAnnotation("feddec.sample"):
+                key, kd = jax.random.split(key)
+                tokens = data.sample(kd, per_agent_batch)
             batch = {"tokens": tokens, "positions": positions}
             state, metrics = step(state, batch, step_key)
-            losses.append(float(metrics["loss"]))
+            with TraceAnnotation("feddec.loss_pull"):
+                losses.append(float(metrics["loss"]))
             log_and_ckpt(i, i + 1)
     if ckpt_dir:
-        save_checkpoint(ckpt_dir, steps,
-                        {"params": ckpt_params(state), "step": state.step})
+        with TraceAnnotation("feddec.ckpt"):
+            save_checkpoint(ckpt_dir, steps,
+                            {"params": ckpt_params(state),
+                             "step": state.step})
     if sweep_runs is not None:
         finals = np.asarray(metrics["loss"][-1])
         print("[train] sweep finals (last-step loss per run): "

@@ -73,11 +73,13 @@ class Model:
         return loss
 
     def grad_fn(self, *, impl: str = "xla", remat: bool = True):
-        """Single-agent (params, batch, key) → (loss, grads) for FedDec."""
+        """Single-agent (params, batch, key) → (loss, grads) for FedDec,
+        under the ``feddec.grad`` scope (the per-agent forward/backward)."""
         def fn(params, batch, key):
-            return jax.value_and_grad(
-                lambda p: self.loss(p, batch, key, impl=impl, remat=remat)
-            )(params)
+            with jax.named_scope("feddec.grad"):
+                return jax.value_and_grad(
+                    lambda p: self.loss(p, batch, key, impl=impl,
+                                        remat=remat))(params)
         return fn
 
     # ---- serving -----------------------------------------------------------

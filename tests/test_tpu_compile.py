@@ -14,6 +14,8 @@ only one process may load libtpu, and under pytest-xdist every worker
 imports this file while only the worker given it runs these tests.
 """
 
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -133,8 +135,15 @@ def test_kernel_compiles_for_v5e(one_chip, name):
     fn, arg_specs = CASES[name]
     args = [_s(one_chip, shape, dtype) for shape, dtype in arg_specs]
     compiled = jax.jit(fn).lower(*args).compile()
+    text = compiled.as_text()
     # the Mosaic kernel is in the program, not an interpret-mode expansion
-    assert "tpu_custom_call" in compiled.as_text(), name
+    assert "tpu_custom_call" in text, name
+    # ... under its public op name, whatever wraps the call
+    kernel = re.sub(r"_(sgd|momentum)$", "", name)
+    calls = re.findall(r"%?([\w.\-]+) = [^\n]*custom_call_target="
+                       r"\"tpu_custom_call\"", text)
+    assert calls and all(re.fullmatch(rf"{kernel}(\.\d+)?", c)
+                         for c in calls), (name, calls)
     # the kernel streams the buffers in place: no padded (n, D) copy
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes < N * BD * 64, (name, mem)
