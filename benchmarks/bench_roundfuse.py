@@ -1,6 +1,6 @@
 """Fused-round benchmark: one-pass update+gossip vs the two-pass body.
 
-Four sections, one JSON:
+Three sections, one JSON:
 
   1. **engine** — the real flat executor at the fig4 linreg shape, fused
      (``fuse_update_mix=True`` → kernels/update_mix.py) vs unfused, across
@@ -20,9 +20,6 @@ Four sections, one JSON:
      devices): ``sharded.boundary_row_split`` row counts, the cost model's
      halo_payload_ratio / predicted_overlap_fraction, measured round
      wall-clock, and a final-buffer check against the unsharded flat round.
-  4. **block_d** — the measured sweep behind ``kernels.ops``'s
-     ``autotune_block_d``: per-tile-width wall-clock at an
-     interpret-feasible shape plus the table's choice at headline widths.
 
 Emits the standard ``name,us_per_call,derived`` CSV lines plus
 results/benchmarks/BENCH_roundfuse.json (consumed by CI's perf-regression
@@ -77,7 +74,6 @@ def _child_main(smoke: bool) -> None:
     from repro.core.feddec import FedDecConfig
     from repro.core.mixing import MixingDistribution
     from repro.data import linreg
-    from repro.kernels import ops as kernel_ops
     from repro.launch import analysis
     from repro.launch.mesh import make_agent_mesh
     from repro.optim import optimizers as optim
@@ -94,12 +90,10 @@ def _child_main(smoke: bool) -> None:
         warmup, iters = 1, 3
         head_n, head_d = 128, 1 << 14
         shard_d, shard_h = 1 << 10, 4
-        block_d_sweep_d = 1 << 12
     else:
         warmup, iters = 1, 3  # the headline rows stream 4 GiB buffers
         head_n, head_d = HEADLINE_N, HEADLINE_D
         shard_d, shard_h = 1 << 12, 8
-        block_d_sweep_d = 1 << 13
 
     def cost_cols(n, d, optimizer, codec):
         cm = analysis.roundfuse_cost_model(
@@ -297,30 +291,6 @@ def _child_main(smoke: bool) -> None:
             f"halo_ratio={cm['halo_payload_ratio']:.3f};"
             f"overlap={cm['predicted_overlap_fraction']:.3f}")
 
-    # -- 4. block_d: the autotune-table sweep ------------------------------
-    n_bd, d_bd = 32, block_d_sweep_d
-    w_bd = jnp.asarray(MixingDistribution(
-        topo.ring_graph(n_bd, k=2), scheme="metropolis").sample(
-            jax.random.key(0)))
-    x_bd = jax.random.normal(jax.random.key(5), (n_bd, d_bd), jnp.float32)
-    g_bd = jax.random.normal(jax.random.key(6), (n_bd, d_bd), jnp.float32)
-    block_rows = []
-    chosen_bd = kernel_ops.autotune_block_d(d_bd, jnp.float32)
-    for bd in (256, 512, 1024, 2048):
-        fn = jax.jit(lambda w, x, g: kernel_ops.update_mix(
-            w, x, g, 0.05, block_d=bd))
-        us = common.time_fn(fn, w_bd, x_bd, g_bd, warmup=warmup, iters=iters)
-        block_rows.append({"section": "block_d", "n_agents": n_bd, "d": d_bd,
-                           "dtype": "float32", "block_d": bd,
-                           "us_per_call": round(us, 1),
-                           "chosen": bd == chosen_bd})
-        common.emit(f"roundfuse_blockd_{bd}_d{d_bd}", us,
-                    f"chosen={bd == chosen_bd}")
-    table_rows = [{"section": "block_d_table", "d": d, "dtype": dt,
-                   "block_d": kernel_ops.autotune_block_d(d, jnp.dtype(dt))}
-                  for d in (1 << 12, 1 << 17, 1 << 20)
-                  for dt in ("float32", "bfloat16")]
-
     head = [r for r in rows if r["section"] == "headline"]
     acceptance = {
         "equivalence_checked_fused_vs_unfused": True,
@@ -348,7 +318,6 @@ def _child_main(smoke: bool) -> None:
            "backend": jax.default_backend(), "smoke": smoke,
            "devices": N_DEVICES,
            "rows": rows, "sharded_rows": sharded_rows,
-           "block_d_rows": block_rows + table_rows,
            "acceptance": acceptance}
     name = "BENCH_roundfuse.smoke.json" if smoke else "BENCH_roundfuse.json"
     path = os.path.join(common.ensure_results_dir(), name)

@@ -25,7 +25,7 @@ commentary) and writes full curves/tables under results/benchmarks/.
   bench_roundfuse  — fused update+gossip round (kernels/update_mix.py):
                      buffer-pass bytes + wall-clock fused vs unfused at
                      fig4 and n=1024, D=2^20, sharded boundary-halo
-                     overlap rows, block_d autotune sweep
+                     overlap rows
   ablation_server  — beyond-paper: §5 conjecture (server vs pure gossip)
   roofline         — aggregates results/dryrun into the §Roofline table
 """

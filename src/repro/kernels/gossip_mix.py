@@ -41,6 +41,9 @@ __all__ = ["gossip_mix_kernel", "gossip_mix_pallas",
 
 BLOCK_D = 2048
 
+# f32 (n, block_d) VMEM scratch tiles of every ELL kernel (ell_mix_tile)
+ELL_SCRATCH = 2
+
 # In-kernel dots contract f32 operands at full f32 precision, as the XLA
 # dense mix does (core.engine's precision=HIGHEST), so the Pallas and dense
 # paths agree whatever Mosaic's default precision is.
@@ -170,9 +173,9 @@ def ell_mix_tile(nbr_ref, base, wv, wd, src32, sbuf, gbuf):
 
 
 def ell_call(kernel, nbr, n_tab, grid, in_specs, out_specs, out_shape,
-             block_d, interpret, *, name, n_scratch=2):
+             block_d, interpret, *, name):
     """pallas_call ``name`` with the (…, n, max_deg) neighbour table ``nbr``
-    scalar-prefetched (flattened) and ``n_scratch`` (n_tab, block_d) f32
+    scalar-prefetched (flattened) and ELL_SCRATCH (n_tab, block_d) f32
     VMEM buffers; ``in_specs``/``out_specs`` index maps take the grid
     indices only (the prefetched table is appended here)."""
     def lift(spec):
@@ -185,7 +188,7 @@ def ell_call(kernel, nbr, n_tab, grid, in_specs, out_specs, out_shape,
         out_specs=jax.tree.map(lift, out_specs,
                                is_leaf=lambda s: isinstance(s, pl.BlockSpec)),
         scratch_shapes=[pltpu.VMEM((n_tab, block_d), jnp.float32)
-                        for _ in range(n_scratch)])
+                        for _ in range(ELL_SCRATCH)])
 
     def call(*args):
         return pl.pallas_call(kernel, grid_spec=grid_spec,

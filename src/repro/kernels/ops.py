@@ -46,42 +46,72 @@ def _interpret() -> bool:
     return not on_tpu()
 
 
-# Measured block_d heuristic (bench_roundfuse.py's block_d sweep): small
-# buffers want tiles no wider than the lane-aligned cover of D (padding a
-# fig-shape D=25 row to 2048 lanes is pure waste — _clamp_block_d already
-# shrinks those), mid-size buffers amortise grid overhead best around 1–2k
-# lanes, and halved itemsizes double the lane count at the same VMEM
-# footprint.  Keyed on (itemsize, D); REPRO_BLOCK_D overrides everything.
-_BLOCK_D_TABLE = {
-    4: ((65536, 512), (1 << 19, 1024), (None, 2048)),
-    2: ((65536, 1024), (1 << 19, 2048), (None, 4096)),
-    1: ((65536, 1024), (1 << 19, 2048), (None, 4096)),
-}
+# The D tile is as wide as a VMEM byte budget allows.  The streaming
+# kernels are HBM-bound, and a Pallas TPU grid step costs a fixed ~0.35 µs
+# whatever it moves, so a narrow tile pays for its grid, not its bytes.
+# What the budget counts, per lane of the tile:
+#   * each streamed (rows, block_d) operand, inputs and outputs, twice
+#     (Pallas double-buffers them), its rows padded to the dtype's sublane
+#     tile (8 rows at 4 B, 16 at 2 B, 32 at 1 B);
+#   * the f32 (rows, block_d) scratch tiles of an ELL kernel;
+#   * _TEMP_TILES more tiles of the widest streamed dtype for the in-tile
+#     temporaries (p, the f32 casts, the dot's result).
+# _VMEM_BUDGET stays well inside v5e's 16 MiB default scoped VMEM, so no
+# kernel needs a vmem_limit_bytes.  _MAX_BLOCK_D comes from timing the
+# fused SGD update+mix alone on a v5e chip, over a (4, 255,864,320) f32
+# buffer: 60.6 ms a call at 2048 lanes (124,934 grid steps), 28.3 ms at
+# 8192, 20.2 ms at 32,768 (74% of the HBM roofline) and 19.0 ms at 65,536.
+# Past 32,768 lanes the grid costs under 3 ms a call, and the budget would
+# count more than 16 MiB for those 4-row tiles.  REPRO_BLOCK_D overrides
+# the width.
+_VMEM_BUDGET = 12 << 20
+_MAX_BLOCK_D = 32768
+_TEMP_TILES = 3
 
 
-def autotune_block_d(d: int, dtype) -> int:
-    """Pick a D tile width for a (·, d) buffer of ``dtype``.
+def _tile_bytes(rows: int, dtype) -> int:
+    """VMEM bytes per lane of a (rows, ·) tile of ``dtype``."""
+    itemsize = jnp.dtype(dtype).itemsize
+    sublanes = 32 // itemsize
+    return -(-rows // sublanes) * sublanes * itemsize
 
-    A tiny measured table (see bench_roundfuse.py's ``block_d`` sweep),
-    not a search: the kernels are bandwidth-bound, so the only live axes
-    are the element size (lane count per byte of VMEM) and whether D is
-    large enough to amortise per-tile grid overhead.  Overridable via the
-    ``REPRO_BLOCK_D`` env var or by passing ``block_d`` explicitly to any
-    wrapper.
+
+def block_d_vmem_bytes(block_d: int, rows: int, streams, *,
+                       scratch: int = 0) -> int:
+    """The VMEM bytes the budget counts for a ``block_d``-wide tile of
+    ``rows`` rows: ``streams`` lists the dtype of every streamed operand
+    (inputs and outputs), ``scratch`` the number of f32 scratch tiles."""
+    widest = max(streams, key=lambda t: jnp.dtype(t).itemsize)
+    per_lane = (2 * sum(_tile_bytes(rows, t) for t in streams)
+                + _TEMP_TILES * _tile_bytes(rows, widest)
+                + scratch * _tile_bytes(rows, jnp.float32))
+    return block_d * per_lane
+
+
+def autotune_block_d(rows: int, streams, *, scratch: int = 0) -> int:
+    """The widest D tile, a multiple of 128 lanes up to _MAX_BLOCK_D, whose
+    :func:`block_d_vmem_bytes` fits _VMEM_BUDGET; never under 128 lanes.
+
+    Overridable via the ``REPRO_BLOCK_D`` env var or by passing
+    ``block_d`` explicitly to any wrapper.
     """
     env = os.environ.get("REPRO_BLOCK_D")
     if env:
         return int(env)
-    itemsize = jnp.dtype(dtype).itemsize
-    for ceiling, block_d in _BLOCK_D_TABLE.get(itemsize, _BLOCK_D_TABLE[4]):
-        if ceiling is None or d <= ceiling:
-            return block_d
-    return _gm.BLOCK_D
+    per_lane = block_d_vmem_bytes(1, rows, streams, scratch=scratch)
+    return max(min(_VMEM_BUDGET // per_lane // 128 * 128, _MAX_BLOCK_D), 128)
 
 
-def _resolve_block_d(block_d: int | None, d: int, dtype) -> int:
+def _resolve_block_d(block_d: int | None, x, streams: int, *,
+                     scratch: int = 0, f32_streams: int = 0) -> int:
+    """``block_d`` or the budget's width for the (…, rows, d) buffer ``x``:
+    ``streams`` operands of x's dtype plus ``f32_streams`` f32 ones (a
+    momentum slot), clamped to the lane-aligned cover of d."""
+    *_, rows, d = x.shape
     if block_d is None:
-        block_d = autotune_block_d(d, dtype)
+        block_d = autotune_block_d(
+            rows, (x.dtype,) * streams + (jnp.float32,) * f32_streams,
+            scratch=scratch)
     return _clamp_block_d(block_d, d)
 
 
@@ -98,21 +128,21 @@ def _clamp_block_d(block_d: int, d: int) -> int:
     """Shrink the D tile to the smallest lane-aligned cover of ``d``.
 
     The 2-D engine hands the kernels (n_local, D/M) sub-blocks of the flat
-    buffer; a full 2048-wide tile over those would compute mostly masked
-    lanes.  The tile stays a multiple of the 128-lane
-    width (f32 min tile is (8, 128)) and never grows past the requested
-    ``block_d``, so large-D callers are untouched.
+    buffer; a full-width tile over those would compute mostly masked
+    lanes.  The tile stays a multiple of the 128-lane width (f32 min tile
+    is (8, 128)) and never grows past the requested ``block_d``, so
+    large-D callers are untouched.
     """
     return max(min(block_d, -(-d // 128) * 128), 128)
 
 
 def gossip_mix(w: jax.Array, x: jax.Array, *,
                block_d: int | None = None):
-    """y = W @ X for (n, D) stacked flats, with the D tile autotuned from
-    (D, dtype) when unset (clamped to the lane-aligned cover of D for
+    """y = W @ X for (n, D) stacked flats, with the D tile sized from the
+    VMEM budget when unset (clamped to the lane-aligned cover of D for
     narrow sub-blocks).  No padding copies: row blocks span all n agents
     and a ragged last D tile is masked by the kernel."""
-    block_d = _resolve_block_d(block_d, x.shape[1], x.dtype)
+    block_d = _resolve_block_d(block_d, x, 2)
     return _gm.gossip_mix_pallas(w, x, block_d=block_d,
                                  interpret=_interpret())
 
@@ -125,7 +155,7 @@ def gossip_mix_batched(w: jax.Array, x: jax.Array, *,
     instead of R dispatches of the single-run kernel; every run's slice is
     bit-identical to the single-run kernel's output.
     """
-    block_d = _resolve_block_d(block_d, x.shape[2], x.dtype)
+    block_d = _resolve_block_d(block_d, x, 2)
     return _gm.gossip_mix_batched_pallas(w, x, block_d=block_d,
                                          interpret=_interpret())
 
@@ -174,7 +204,7 @@ def make_sparse_gossip_pallas(graph, *, block_d: int | None = None):
 
     def mix(w: jax.Array, x: jax.Array) -> jax.Array:
         assert x.shape[0] == graph.n, (x.shape, graph.n)
-        bd = _resolve_block_d(block_d, x.shape[1], x.dtype)
+        bd = _resolve_block_d(block_d, x, 2, scratch=_gm.ELL_SCRATCH)
         wv, wd = _ell_weights(w, nbr, valid)
         return _gm.gossip_mix_sparse_pallas(nbr, wv, wd, x, block_d=bd,
                                             interpret=_interpret())
@@ -198,7 +228,7 @@ def make_sparse_gossip_batched_pallas(graphs, *,
 
     def mix(w: jax.Array, x: jax.Array) -> jax.Array:
         assert x.shape[:2] == nbr.shape[:2], (x.shape, nbr.shape)
-        bd = _resolve_block_d(block_d, x.shape[2], x.dtype)
+        bd = _resolve_block_d(block_d, x, 2, scratch=_gm.ELL_SCRATCH)
         wv, wd = _ell_weights(w, nbr, valid)
         return _gm.gossip_mix_sparse_batched_pallas(
             nbr, wv, wd, x, block_d=bd, interpret=_interpret())
@@ -222,7 +252,7 @@ def update_mix(w, x, g, eta, *, m=None, beta=None, nesterov=False,
     Returns y, or (y, new_m) when a momentum buffer ``m`` is passed with
     ``beta``.
     """
-    bd = _resolve_block_d(block_d, x.shape[1], x.dtype)
+    bd = _resolve_block_d(block_d, x, 3, f32_streams=0 if m is None else 2)
     if m is None:
         return _um.update_mix_pallas(w, x, g, _eta(eta), block_d=bd,
                                      interpret=_interpret())
@@ -235,7 +265,7 @@ def update_mix(w, x, g, eta, *, m=None, beta=None, nesterov=False,
 def update_mix_batched(w, x, g, eta, *, m=None, beta=None, nesterov=False,
                        block_d: int | None = None):
     """Batched fused update + mix over (R, n, D) run buffers; eta (R,)."""
-    bd = _resolve_block_d(block_d, x.shape[2], x.dtype)
+    bd = _resolve_block_d(block_d, x, 3, f32_streams=0 if m is None else 2)
     eta2 = _eta(eta, x.shape[0])
     if m is None:
         return _um.update_mix_batched_pallas(w, x, g, eta2, block_d=bd,
@@ -255,7 +285,8 @@ def _make_sparse_update_mix(graphs, batched, beta, nesterov, block_d):
 
     def fused(w, x, g, eta, m=None):
         assert x.shape[:-1] == nbr.shape[:-1], (x.shape, nbr.shape)
-        bd = _resolve_block_d(block_d, x.shape[-1], x.dtype)
+        bd = _resolve_block_d(block_d, x, 3, scratch=_gm.ELL_SCRATCH,
+                              f32_streams=0 if m is None else 2)
         wv, wd = _ell_weights(w, nbr, valid)
         eta2 = _eta(eta, x.shape[0] if batched else 1)
         if m is None:
@@ -292,14 +323,14 @@ def ef_mix(w, p, s, u, *, block_d: int | None = None):
     The encode (whole-row reductions) stays on the shared XLA codec; this
     replaces the mix + correction + residual triple of passes.
     """
-    bd = _resolve_block_d(block_d, p.shape[1], p.dtype)
+    bd = _resolve_block_d(block_d, p, 5)
     return _um.ef_mix_pallas(w, jnp.diagonal(w), p, s, u, block_d=bd,
                              interpret=_interpret())
 
 
 def ef_mix_batched(w, p, s, u, *, block_d: int | None = None):
     """Batched fused EF receive side over (R, n, D) run buffers."""
-    bd = _resolve_block_d(block_d, p.shape[2], p.dtype)
+    bd = _resolve_block_d(block_d, p, 5)
     return _um.ef_mix_batched_pallas(
         w, jnp.diagonal(w, axis1=1, axis2=2), p, s, u, block_d=bd,
         interpret=_interpret())
@@ -314,7 +345,7 @@ def _make_sparse_ef_mix(graphs, batched, block_d):
 
     def ef(w, p, s, u):
         assert p.shape[:-1] == nbr.shape[:-1], (p.shape, nbr.shape)
-        bd = _resolve_block_d(block_d, p.shape[-1], p.dtype)
+        bd = _resolve_block_d(block_d, p, 5, scratch=_gm.ELL_SCRATCH)
         wv, wd = _ell_weights(w, nbr, valid)
         return kernel(nbr, wv, wd, p, s, u, block_d=bd,
                       interpret=_interpret())

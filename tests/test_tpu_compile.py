@@ -6,14 +6,18 @@ dynamic index, a block that does not tile (8, 128).  These tests hand each
 kernel to the TPU compiler that libtpu ships, for a ``v5e:2x2`` topology
 that is described and not attached, at the shapes ``chip_smoke.py`` trains:
 4 agents of the 156.5M-parameter tiny LM (flat D = 156,519,168, a ragged
-last tile), ring graph (ELL max degree 2), f32.  Nothing runs; a compile
-that passes is not a chip run.
+last tile), ring graph (ELL max degree 2), f32, each kernel at the D tile
+``kernels.ops`` sizes for it from its VMEM budget; and the Qwen1.5-4B
+benchmark cell's fused update+mix at its own D = 255,864,320, to show
+that the budget's tile fits VMEM.  Nothing runs; a compile that passes is
+not a chip run.
 
 The topology is described inside a module-scoped fixture, never at import:
 only one process may load libtpu, and under pytest-xdist every worker
 imports this file while only the worker given it runs these tests.
 """
 
+import functools
 import re
 
 import jax
@@ -22,11 +26,19 @@ import pytest
 
 from repro.kernels import compress_mix as cm
 from repro.kernels import gossip_mix as gm
+from repro.kernels import ops
 from repro.kernels import update_mix as um
 
 N, DEG, R = 4, 2, 2
 D = 156_519_168          # tiny_lm_config() flat size (d_model 768, 12 layers)
-BD = 2048                # ops.autotune_block_d(D, f32)
+D_CELL = 255_864_320     # the Qwen1.5-4B benchmark cell's flat size
+
+
+def _bd(streams, f32_streams=0, scratch=0):
+    """The D tile kernels.ops gives a call of ``streams`` f32 operands
+    (``f32_streams`` more for a momentum slot, ``scratch`` ELL tiles)."""
+    return ops.autotune_block_d(N, (jnp.float32,) * (streams + f32_streams),
+                                scratch=scratch)
 
 
 @pytest.fixture(scope="module")
@@ -58,92 +70,92 @@ def _f32(*shape):
 
 _ELL = ((N, DEG), jnp.int32)
 _ELL_R = ((R, N, DEG), jnp.int32)
+_ELL_S = dict(scratch=gm.ELL_SCRATCH)
+_MOM = dict(beta=0.9)
 
-# name -> (kernel called with interpret=False, argument (shape, dtype)s)
+# name -> (kernel, its static keywords, argument (shape, dtype)s, block_d);
+# every kernel but dequant_mix takes the D tile the VMEM budget gives it
 CASES = {
     "gossip_mix": (
-        lambda w, x: gm.gossip_mix_pallas(w, x, block_d=BD),
-        [_f32(N, N), _f32(N, D)]),
+        gm.gossip_mix_pallas, {}, [_f32(N, N), _f32(N, D)], _bd(2)),
     "gossip_mix_batched": (
-        lambda w, x: gm.gossip_mix_batched_pallas(w, x, block_d=BD),
-        [_f32(R, N, N), _f32(R, N, D)]),
+        gm.gossip_mix_batched_pallas, {},
+        [_f32(R, N, N), _f32(R, N, D)], _bd(2)),
     "update_mix_sgd": (
-        lambda w, x, g, e: um.update_mix_pallas(w, x, g, e, block_d=BD),
-        [_f32(N, N), _f32(N, D), _f32(N, D), _f32(1, 1)]),
+        um.update_mix_pallas, {},
+        [_f32(N, N), _f32(N, D), _f32(N, D), _f32(1, 1)], _bd(3)),
+    # the benchmark cell's kernel at its own D: its VMEM fits at compile
+    "update_mix_sgd_cell": (
+        um.update_mix_pallas, {},
+        [_f32(N, N), _f32(N, D_CELL), _f32(N, D_CELL), _f32(1, 1)], _bd(3)),
     "update_mix_momentum": (
-        lambda w, x, g, e, m: um.update_mix_pallas(
-            w, x, g, e, m, beta=0.9, block_d=BD),
-        [_f32(N, N), _f32(N, D), _f32(N, D), _f32(1, 1), _f32(N, D)]),
+        um.update_mix_pallas, _MOM,
+        [_f32(N, N), _f32(N, D), _f32(N, D), _f32(1, 1), _f32(N, D)],
+        _bd(3, 2)),
     "update_mix_batched_momentum": (
-        lambda w, x, g, e, m: um.update_mix_batched_pallas(
-            w, x, g, e, m, beta=0.9, block_d=BD),
+        um.update_mix_batched_pallas, _MOM,
         [_f32(R, N, N), _f32(R, N, D), _f32(R, N, D), _f32(R, 1),
-         _f32(R, N, D)]),
+         _f32(R, N, D)], _bd(3, 2)),
     "ef_mix": (
-        lambda w, dg, p, s, u: um.ef_mix_pallas(w, dg, p, s, u, block_d=BD),
-        [_f32(N, N), _f32(N), _f32(N, D), _f32(N, D), _f32(N, D)]),
+        um.ef_mix_pallas, {},
+        [_f32(N, N), _f32(N), _f32(N, D), _f32(N, D), _f32(N, D)], _bd(5)),
     "ef_mix_batched": (
-        lambda w, dg, p, s, u: um.ef_mix_batched_pallas(
-            w, dg, p, s, u, block_d=BD),
+        um.ef_mix_batched_pallas, {},
         [_f32(R, N, N), _f32(R, N), _f32(R, N, D), _f32(R, N, D),
-         _f32(R, N, D)]),
+         _f32(R, N, D)], _bd(5)),
     "dequant_mix": (
-        lambda w, dg, sc, q, p: cm.dequant_mix_pallas(
-            w, dg, sc, q, p, block_d=BD),
-        [_f32(N, N), _f32(N), _f32(N), ((N, D), jnp.int8), _f32(N, D)]),
+        cm.dequant_mix_pallas, {},
+        [_f32(N, N), _f32(N), _f32(N), ((N, D), jnp.int8), _f32(N, D)],
+        cm.BLOCK_D),
     "gossip_mix_sparse": (
-        lambda nb, wv, wd, x: gm.gossip_mix_sparse_pallas(
-            nb, wv, wd, x, block_d=BD),
-        [_ELL, _f32(N, DEG), _f32(N), _f32(N, D)]),
+        gm.gossip_mix_sparse_pallas, {},
+        [_ELL, _f32(N, DEG), _f32(N), _f32(N, D)], _bd(2, **_ELL_S)),
     "gossip_mix_sparse_batched": (
-        lambda nb, wv, wd, x: gm.gossip_mix_sparse_batched_pallas(
-            nb, wv, wd, x, block_d=BD),
-        [_ELL_R, _f32(R, N, DEG), _f32(R, N), _f32(R, N, D)]),
+        gm.gossip_mix_sparse_batched_pallas, {},
+        [_ELL_R, _f32(R, N, DEG), _f32(R, N), _f32(R, N, D)],
+        _bd(2, **_ELL_S)),
     "update_mix_sparse_sgd": (
-        lambda nb, wv, wd, x, g, e: um.update_mix_sparse_pallas(
-            nb, wv, wd, x, g, e, block_d=BD),
-        [_ELL, _f32(N, DEG), _f32(N), _f32(N, D), _f32(N, D), _f32(1, 1)]),
+        um.update_mix_sparse_pallas, {},
+        [_ELL, _f32(N, DEG), _f32(N), _f32(N, D), _f32(N, D), _f32(1, 1)],
+        _bd(3, **_ELL_S)),
     "update_mix_sparse_momentum": (
-        lambda nb, wv, wd, x, g, e, m: um.update_mix_sparse_pallas(
-            nb, wv, wd, x, g, e, m, beta=0.9, nesterov=True, block_d=BD),
+        um.update_mix_sparse_pallas, dict(_MOM, nesterov=True),
         [_ELL, _f32(N, DEG), _f32(N), _f32(N, D), _f32(N, D), _f32(1, 1),
-         _f32(N, D)]),
+         _f32(N, D)], _bd(3, 2, **_ELL_S)),
     "update_mix_sparse_batched_sgd": (
-        lambda nb, wv, wd, x, g, e: um.update_mix_sparse_batched_pallas(
-            nb, wv, wd, x, g, e, block_d=BD),
+        um.update_mix_sparse_batched_pallas, {},
         [_ELL_R, _f32(R, N, DEG), _f32(R, N), _f32(R, N, D),
-         _f32(R, N, D), _f32(R, 1)]),
+         _f32(R, N, D), _f32(R, 1)], _bd(3, **_ELL_S)),
     "update_mix_sparse_batched_momentum": (
-        lambda nb, wv, wd, x, g, e, m: um.update_mix_sparse_batched_pallas(
-            nb, wv, wd, x, g, e, m, beta=0.9, block_d=BD),
+        um.update_mix_sparse_batched_pallas, _MOM,
         [_ELL_R, _f32(R, N, DEG), _f32(R, N), _f32(R, N, D),
-         _f32(R, N, D), _f32(R, 1), _f32(R, N, D)]),
+         _f32(R, N, D), _f32(R, 1), _f32(R, N, D)], _bd(3, 2, **_ELL_S)),
     "ef_mix_sparse": (
-        lambda nb, wv, wd, p, s, u: um.ef_mix_sparse_pallas(
-            nb, wv, wd, p, s, u, block_d=BD),
-        [_ELL, _f32(N, DEG), _f32(N), _f32(N, D), _f32(N, D), _f32(N, D)]),
+        um.ef_mix_sparse_pallas, {},
+        [_ELL, _f32(N, DEG), _f32(N), _f32(N, D), _f32(N, D), _f32(N, D)],
+        _bd(5, **_ELL_S)),
     "ef_mix_sparse_batched": (
-        lambda nb, wv, wd, p, s, u: um.ef_mix_sparse_batched_pallas(
-            nb, wv, wd, p, s, u, block_d=BD),
+        um.ef_mix_sparse_batched_pallas, {},
         [_ELL_R, _f32(R, N, DEG), _f32(R, N), _f32(R, N, D), _f32(R, N, D),
-         _f32(R, N, D)]),
+         _f32(R, N, D)], _bd(5, **_ELL_S)),
 }
 
 
 @pytest.mark.parametrize("name", sorted(CASES))
 def test_kernel_compiles_for_v5e(one_chip, name):
-    fn, arg_specs = CASES[name]
+    fn, static, arg_specs, bd = CASES[name]
     args = [_s(one_chip, shape, dtype) for shape, dtype in arg_specs]
-    compiled = jax.jit(fn).lower(*args).compile()
+    compiled = jax.jit(functools.partial(fn, block_d=bd, **static)).lower(
+        *args).compile()
     text = compiled.as_text()
     # the Mosaic kernel is in the program, not an interpret-mode expansion
     assert "tpu_custom_call" in text, name
     # ... under its public op name, whatever wraps the call
-    kernel = re.sub(r"_(sgd|momentum)$", "", name)
+    kernel = re.sub(r"_(sgd|momentum)(_cell)?$", "", name)
     calls = re.findall(r"%?([\w.\-]+) = [^\n]*custom_call_target="
                        r"\"tpu_custom_call\"", text)
     assert calls and all(re.fullmatch(rf"{kernel}(\.\d+)?", c)
                          for c in calls), (name, calls)
     # the kernel streams the buffers in place: no padded (n, D) copy
     mem = compiled.memory_analysis()
-    assert mem.temp_size_in_bytes < N * BD * 64, (name, mem)
+    assert mem.temp_size_in_bytes < N * bd * 64, (name, mem)

@@ -8,8 +8,9 @@ Four tiers, mirroring tests/test_compress.py's layout:
     nesterov, and the EF ``ef_mix`` family — against the unfused two-pass
     XLA composition, across f32/bf16, non-block_d-aligned D (padding) and
     uneven-degree graphs (ELL degree padding);
-  * the block_d autotune table and its REPRO_BLOCK_D override, and the
-    backend rule for interpret mode;
+  * the block_d VMEM budget rule, its REPRO_BLOCK_D override, the budget
+    width's bit identity with a 2048-lane tile, and the backend rule for
+    interpret mode;
   * engine-level trajectories: ``fuse_update_mix=True`` matches the
     unfused flat/sweep engines to 1e-5 across impls × sgd/momentum ×
     codec on/off; adamw (no fused kernel) falls back bit-identically;
@@ -210,17 +211,84 @@ def test_ef_mix_batched():
 # ---------------------------------------------------------------------------
 
 
-def test_autotune_block_d_table():
-    assert kernel_ops.autotune_block_d(1 << 12, jnp.float32) == 512
-    assert kernel_ops.autotune_block_d(1 << 17, jnp.float32) == 1024
-    assert kernel_ops.autotune_block_d(1 << 20, jnp.float32) == 2048
-    # halved itemsize doubles the lane count at the same VMEM footprint
-    assert kernel_ops.autotune_block_d(1 << 20, jnp.bfloat16) == 4096
+_F32, _BF16 = jnp.float32, jnp.bfloat16
+
+
+# (rows, d, dtype, streams of that dtype, f32 streams, f32 scratch tiles)
+# -> the width the VMEM budget gives
+@pytest.mark.parametrize("rows,d,dtype,streams,f32_streams,scratch,width", [
+    # the benchmark cell's fused SGD update+mix: x, g in, y out
+    (4, 255_864_320, _F32, 3, 0, 0, 32768),
+    # momentum streams the f32 slot in and out as well
+    (4, 255_864_320, _F32, 3, 2, 0, 30208),
+    # 64 agents: the budget, not the cap, sets the width
+    (64, 1 << 30, _F32, 3, 0, 0, 5376),
+    # ... and bf16 rows are half the bytes, so twice the lanes
+    (64, 1 << 30, _BF16, 3, 0, 0, 10880),
+    # 1024 agents on an ELL kernel: one (1024, 2048) f32 tile alone was
+    # 8 MiB
+    (1024, 1 << 24, _F32, 3, 0, 2, 256),
+    # a small D clamps to its 128-lane cover
+    (4, 1000, _F32, 3, 0, 0, 1024),
+])
+def test_autotune_block_d_budget(rows, d, dtype, streams, f32_streams,
+                                 scratch, width):
+    x = jax.ShapeDtypeStruct((rows, d), dtype)
+    got = kernel_ops._resolve_block_d(None, x, streams, scratch=scratch,
+                                      f32_streams=f32_streams)
+    assert got == width
+    dtypes = (dtype,) * streams + (_F32,) * f32_streams
+    assert kernel_ops.block_d_vmem_bytes(
+        got, rows, dtypes, scratch=scratch) <= kernel_ops._VMEM_BUDGET
+    if dtype == _BF16:
+        f32_width = kernel_ops.autotune_block_d(rows, (_F32,) * streams,
+                                                scratch=scratch)
+        assert got >= 2 * f32_width
+        assert kernel_ops.block_d_vmem_bytes(
+            2 * f32_width, rows, dtypes, scratch=scratch) == \
+            kernel_ops.block_d_vmem_bytes(f32_width, rows, (_F32,) * streams,
+                                          scratch=scratch)
 
 
 def test_autotune_block_d_env_override(monkeypatch):
     monkeypatch.setenv("REPRO_BLOCK_D", "128")
-    assert kernel_ops.autotune_block_d(1 << 20, jnp.float32) == 128
+    assert kernel_ops.autotune_block_d(4, (jnp.float32,) * 3) == 128
+
+
+@pytest.mark.parametrize("data", ["exact", "random"])
+def test_update_mix_budget_width_bit_identical(data):
+    """The budget's wide tile changes no bit of y: every output column is
+    the same contraction with W whatever the tile width.  D is a multiple
+    of neither width, so both ragged last tiles are covered.
+
+    ``exact``: a lazy-ring W (1/2, 1/4, 1/4), x and g on a 1/8 grid and
+    eta 1/2, so every sum is exact in f32 and y is the same bits in any
+    summation order, here as on the chip.  ``random``: on the chip the
+    MXU's y is bit-identical too, but interpret mode hands the tile's dot
+    to XLA:CPU, whose summation order depends on the tile's width: y may
+    then differ by the rounding of a 4-term sum, never by more."""
+    d = 70_001
+    if data == "exact":
+        w = 0.5 * jnp.eye(4) + 0.25 * (jnp.roll(jnp.eye(4), 1, 0)
+                                       + jnp.roll(jnp.eye(4), -1, 0))
+        x, g = (jax.random.randint(jax.random.key(k), (4, d), -64, 64)
+                .astype(jnp.float32) / 8 for k in (1, 2))
+        eta = 0.5
+    else:
+        w = jnp.asarray(MixingDistribution(
+            topo.ring_graph(4, k=1), scheme="metropolis").sample(
+                jax.random.key(0)), jnp.float32)
+        x, g = _rand((4, d), 1), _rand((4, d), 2)
+        eta = 0.05
+    width = kernel_ops._resolve_block_d(None, x, 3)
+    assert width > 2048 and d % width and d % 2048
+    y = np.asarray(kernel_ops.update_mix(w, x, g, eta))
+    ref = np.asarray(kernel_ops.update_mix(w, x, g, eta, block_d=2048))
+    if data == "exact":
+        np.testing.assert_array_equal(y, ref)
+    else:
+        terms = np.abs(np.asarray(w)) @ np.abs(np.asarray(x - eta * g))
+        assert np.all(np.abs(y - ref) <= 4 * np.finfo(np.float32).eps * terms)
 
 
 def test_interpret_env_override(monkeypatch):
