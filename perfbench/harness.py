@@ -15,8 +15,10 @@ have passed it sends nothing more, waits for every round it sent, and
 reads the clock after that wait.  Garbage is collected and frozen before
 the window, so that no collection of set-up's objects falls inside it.
 With ``trace`` the window runs under the profiler, and the per-layer
-metrics are read from the trace; every metric, end to end or per layer,
-is read by its own reader, ``metrics/<name>.py``.
+metrics are read from the trace, the round's compiled text (its kernels,
+and each instruction's phase, ``scopes.py``) and the reference's FLOP
+count; every metric, end to end or per layer, is read by its own reader,
+``metrics/<name>.py``.
 
 After the window the peak memory is read, the program's state is freed,
 and the reference runs the checked round.
@@ -36,15 +38,15 @@ from functools import partial
 
 import numpy as np
 
-from perfbench import algorithm1, compare, counts, faults, traffic
+from perfbench import algorithm1, compare, counts, faults, scopes, traffic
 from perfbench import trace as trace_lib
 from perfbench.peaks import peaks_for
 from perfbench.seeds import purpose_key
 from perfbench.spec import HERE, Cell, arch_config, load_module
 from perfbench.weights import make_weights
 
-__all__ = ["run", "checked_round", "reference", "device_info",
-           "COMPILE_EVENTS"]
+__all__ = ["run", "checked_round", "reference", "reference_model",
+           "device_info", "COMPILE_EVENTS"]
 
 SAME = faults.wrappers(None)
 
@@ -162,10 +164,20 @@ def _kernel_names(compiled_text: str) -> list[str]:
                       r"\"tpu_custom_call\"", compiled_text)
 
 
+def reference_model(cell: Cell):
+    """The cell's plain model, ``references/<reference>.py``: its
+    ``loss`` and ``flops_per_token``."""
+    return load_module(HERE / "references"
+                       / f"{cell.config['reference']}.py")
+
+
 def run(cell: Cell, *, seed: int, seconds: float, trace: bool,
-        t_start: float, fault: str | None = None) -> dict:
+        t_start: float, fault: str | None = None,
+        keep_ctx: dict | None = None) -> dict:
     """Run the cell once; returns the result object (the last stdout
-    line).  ``fault`` plants one of ``faults.FAULTS`` in the program."""
+    line).  ``fault`` plants one of ``faults.FAULTS`` in the program;
+    ``keep_ctx`` receives the readers' context, which under ``trace`` also
+    holds the round's compiled ``text``, its ``scopes`` and the trace."""
     import jax
 
     from repro.launch.compile_cache import enable_compile_cache
@@ -186,11 +198,9 @@ def run(cell: Cell, *, seed: int, seconds: float, trace: bool,
         phases["checked_round"] = time.time() - t_start - sum(
             phases.values())
         compile_s = comp["s"]
-        kernels = []
         if trace:
-            kernels = _kernel_names(
-                round_fn.lower(state, batches[1 % len(batches)], key)
-                .compile().as_text())
+            text = round_fn.lower(state, batches[1 % len(batches)],
+                                  key).compile().as_text()
 
         t_window = time.time()
         setup_s = t_window - t_start
@@ -255,10 +265,11 @@ def run(cell: Cell, *, seed: int, seconds: float, trace: bool,
         device["busy_s"] = float(np.mean(busy)) / 1e9
         device["window_s"] = (hi - lo) / 1e9
         ctx.update(
-            trace=tr_data, lo=lo, hi=hi, device_ids=ids,
-            flops_per_step=counts.model_flops_per_token(arch, seq)
+            trace=tr_data, lo=lo, hi=hi, device_ids=ids, text=text,
+            scopes=scopes.hlo_scopes(text), kernels=_kernel_names(text),
+            flops_per_step=reference_model(cell).flops_per_token(arch, seq)
             * n * batch * seq,
-            peaks=peaks_for(device["kind"]), kernels=kernels,
+            peaks=peaks_for(device["kind"]),
             update_mix_bytes=counts.update_mix_bytes(n, spec.d)
             if tr["fuse_update_mix"] else None)
         d0 = tr_data.devices[ids[0]] if ids else []
@@ -270,6 +281,8 @@ def run(cell: Cell, *, seed: int, seconds: float, trace: bool,
         value = load_module(HERE / "metrics" / f"{m['name']}.py").read(ctx)
         if value is not None:
             result_metrics[m["name"]] = {"value": value, "unit": m["unit"]}
+    if keep_ctx is not None:
+        keep_ctx.update(ctx)
 
     ref = reference(cell, b, s)
     nums = compare.numbers(warm_losses, change, spread, ref)
@@ -299,8 +312,7 @@ def reference(cell: Cell, b: dict, s: dict, mm: str = "float32",
 
     tr = cell.traffic
     n = b["n"]
-    loss_fn = load_module(HERE / "references"
-                          / f"{cell.config['reference']}.py").loss
+    loss_fn = reference_model(cell).loss
     w = algorithm1.ring_metropolis(n, min(_ring_k(tr["graph"]),
                                           (n - 1) // 2 or 1))
     tokens, lr = s["pool"][0], tr["lr"]

@@ -1,6 +1,6 @@
 """The whole training step's share of the chips' bf16 peak: model FLOPs of
-the steps completed in the traced window (perfbench/counts.py, no
-recomputation) over window x chips x peak."""
+the steps completed in the traced window (the cell's reference's
+``flops_per_token``, no recomputation) over window x chips x peak."""
 
 
 def read(ctx):

@@ -1,5 +1,16 @@
 """Plain reference models, one file each, named by a configuration's
-``reference`` key.  Each file defines ``loss(params, tokens, arch, mm)``."""
+``reference`` key.  Each file defines
+
+* ``loss(params, tokens, arch, mm)``: the mean next-token cross entropy of
+  ``tokens`` under the parameter tree ``params``, every product through
+  ``mm``, which the caller sets to a precision;
+* ``flops_per_token(arch, seq)``: the operations the forward and backward
+  passes of that model require per training token at sequence length
+  ``seq``, recomputation not counted: what ``mfu_pct`` and
+  ``grad_flops_pct`` count as the step's work.
+
+``arch`` is the configuration file's ``arch`` object, as JSON gives it.
+"""
 
 from __future__ import annotations
 

@@ -11,6 +11,13 @@ It reads the parameter tree the benchmark made (``perfbench/weights.py``)
 by its keys.  Where the program departs from the published model, the
 reference follows the program, and the configuration file lists each
 departure (the ``sqrt(d_model)`` embedding scale; RMSNorm as ``1 + scale``).
+
+``flops_per_token`` counts what the forward and backward passes of this
+model require per token: every matrix product (2 operations per
+multiply-add), the attention score and value products over the causal
+half that a token attends to, times 3 for forward plus backward.
+Recomputation (the program's remat) is not counted.  Elementwise work,
+norms and the softmax are left out.
 """
 
 from __future__ import annotations
@@ -20,7 +27,7 @@ import jax.numpy as jnp
 
 from perfbench.references import layer_params
 
-__all__ = ["loss"]
+__all__ = ["loss", "flops_per_token"]
 
 
 def _rms(x, scale, eps):
@@ -80,3 +87,21 @@ def loss(params, tokens, arch, mm):
     logits = logits[:, :-1]
     gold = jnp.take_along_axis(logits, tokens[:, 1:, None], axis=-1)[..., 0]
     return jnp.mean(jax.nn.logsumexp(logits, axis=-1) - gold)
+
+
+def _dense_layer(arch: dict, seq: int) -> float:
+    d, h, kv = arch["d_model"], arch["num_heads"], arch["num_kv_heads"]
+    hd = arch.get("head_dim") or d // h
+    proj = 2 * d * hd * (2 * h + 2 * kv)                # q, k, v, o
+    mlp = 2 * d * arch["d_ff"] * (3 if arch.get("mlp_kind", "swiglu")
+                                  in ("swiglu", "geglu") else 2)
+    attn = 2 * 2 * h * hd * (seq + 1) / 2               # QK^T and PV, causal
+    return proj + mlp + attn
+
+
+def flops_per_token(arch: dict, seq: int) -> float:
+    """Training operations per token (forward + backward = 3 x forward)."""
+    if arch["arch_type"] != "dense":
+        raise ValueError(f"no FLOP count for {arch['arch_type']!r} layers")
+    head = 2 * arch["d_model"] * arch["vocab_size"]
+    return 3.0 * (arch["num_layers"] * _dense_layer(arch, seq) + head)

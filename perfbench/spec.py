@@ -7,6 +7,8 @@ per-layer metric is a file of its own, found from the names in
 * ``configs/<config>.json``   the cut ArchConfig (``arch``) with its source,
   ``reduced``, ``assumed`` and deployment; ``reference`` names the plain
   model in ``references/<reference>.py``;
+* ``references/<reference>.py``  that model's ``loss`` and
+  ``flops_per_token`` (``references/__init__.py``);
 * ``traffic/<traffic>.json``  agents, graph, H, K, optimizer, batch, seq,
   gossip path and fusion, and the size of the token pool;
 * ``limits/<workload>.json``  the limit of each number that decides
@@ -14,8 +16,15 @@ per-layer metric is a file of its own, found from the names in
 * ``metrics/<metric>.py``     a ``read(ctx)`` that returns the metric, or
   None where it finds nothing to read.
 
-A later cell, configuration or metric is added by adding files and
-entries; no file here names one.
+So a configuration of any architecture the program registers brings its
+``configs/`` file, a ``references/`` file where none there computes its
+model yet, a ``limits/`` file for each of its cells, and a ``traffic/``
+or ``metrics/`` file only for a new mix or metric, with their entries in
+``BENCHMARK.json``.  Its ``arch`` holds the ``ArchConfig`` fields: the
+nested configs (``moe``, ``mla``, ``ssm``) as objects of their fields,
+tuples (``block_pattern``) as lists, dtypes by name.  Its weights come
+from ``weights.py``'s rules by leaf name.  No file here names a
+configuration, a cell or an architecture.
 """
 
 from __future__ import annotations
@@ -25,7 +34,7 @@ import importlib.util
 import json
 from pathlib import Path
 from types import ModuleType
-from typing import Any
+from typing import Any, get_args, get_type_hints
 
 HERE = Path(__file__).resolve().parent
 ROOT = HERE.parent
@@ -81,13 +90,34 @@ def load_cell(workload: str, bench: dict | None = None) -> Cell:
                         if _reports(m, workload)))
 
 
+def _nested_type(hint) -> type:
+    """The dataclass of a field typed ``SomeConfig | None``."""
+    return next(t for t in get_args(hint) or (hint,)
+                if dataclasses.is_dataclass(t))
+
+
+def _fields(cls: type, values: dict) -> dict:
+    """``values`` of a JSON object as ``cls``'s fields: nested objects as
+    the dataclasses their fields are typed with, lists as tuples."""
+    hints = get_type_hints(cls)
+    out = {}
+    for key, value in values.items():
+        if isinstance(value, dict):
+            nested = _nested_type(hints[key])
+            value = nested(**_fields(nested, value))
+        elif isinstance(value, list):
+            value = tuple(value)
+        out[key] = value
+    return out
+
+
 def arch_config(config: dict, **overrides: Any):
     """The program's ArchConfig for a configuration file's ``arch``."""
     import jax.numpy as jnp
 
     from repro.configs.base import ArchConfig
 
-    kw = dict(config["arch"], **overrides)
+    kw = _fields(ArchConfig, dict(config["arch"], **overrides))
     for key in ("param_dtype", "compute_dtype"):
         kw[key] = jnp.dtype(kw[key])
     return ArchConfig(source=config["source"], **kw)

@@ -1,5 +1,5 @@
-"""Model FLOPs, kernel bytes, peaks, seeds, traffic and the benchmark's
-files, on known shapes."""
+"""Model FLOPs (each reference's ``flops_per_token``), kernel bytes, peaks,
+seeds, traffic and the benchmark's files, on known shapes."""
 
 import json
 import re
@@ -9,9 +9,14 @@ import numpy as np
 import pytest
 
 from perfbench import counts, peaks, seeds, traffic
-from perfbench.spec import HERE, ROOT, load_benchmark, load_cell
+from perfbench.spec import HERE, ROOT, load_benchmark, load_cell, load_module
 
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
+
+
+def _flops_per_token(reference: str):
+    return load_module(HERE / "references" / f"{reference}.py"
+                       ).flops_per_token
 
 
 def test_dense_flops_by_hand():
@@ -23,15 +28,17 @@ def test_dense_flops_by_hand():
     mlp = 2 * 8 * 16 * 3
     attn = 2 * 2 * 2 * 4 * 3                # causal: (5 + 1) / 2 keys
     fwd = 2 * (proj + mlp + attn) + 2 * 8 * 32
-    assert counts.model_flops_per_token(arch, seq) == 3 * fwd
+    assert _flops_per_token("dense_transformer")(arch, seq) == 3 * fwd
 
 
 def test_qwen_cut_is_about_six_n():
     """Matmul FLOPs dominate: forward + backward is ~6 x parameters."""
-    arch = load_cell("qwen1.5-4b.ring-short").config["arch"]
+    config = load_cell("qwen1.5-4b.ring-short").config
+    arch = config["arch"]
     d, ff, v = arch["d_model"], arch["d_ff"], arch["vocab_size"]
     matmul_params = arch["num_layers"] * (4 * d * d + 3 * d * ff) + d * v
-    per_token = counts.model_flops_per_token(arch, 512)
+    per_token = _flops_per_token(config["reference"])(arch, 512)
+    assert per_token == 1_259_059_200.0
     assert 6 * matmul_params < per_token < 6.1 * matmul_params
 
 
@@ -89,6 +96,7 @@ def test_benchmark_files_are_complete():
     for w in bench["workloads"]:
         cell = load_cell(w["name"], bench)
         assert set(cell.limits) == {"loss_gap", "change_gap", "spread_gap"}
-        assert (HERE / "references"
-                / f"{cell.config['reference']}.py").is_file()
+        ref = load_module(HERE / "references"
+                          / f"{cell.config['reference']}.py")
+        assert callable(ref.loss) and callable(ref.flops_per_token)
         assert {m["name"] for m in cell.end_to_end} >= {"setup_s"}

@@ -6,8 +6,8 @@ import re
 
 import pytest
 
-from perfbench import counts, harness, phases, scopes
-from perfbench.tests.test_perfbench_trace import MS, _ctx, _trace
+from perfbench import harness, phases, scopes
+from perfbench.tests.test_perfbench_trace import MS, _ctx, _read, _trace
 from perfbench.tests.tiny import tiny_cell
 
 CELL = "qwen1.5-4b.ring-short"
@@ -116,21 +116,25 @@ def test_phase_readings_where_nothing_ran():
 
 
 def test_phase_report_plumbing():
-    cell = tiny_cell(CELL)
-    tr = cell.traffic
-    result = {"attempted": 3, "device": {"kind": "TPU v5 lite"}}
     text = SNIPPET.replace("update_mix.1", "custom-call.7").replace(
         "fusion.3", "fusion.1")
-    out = phases.phase_report(cell, result, text, _trace())
-    assert out["window_s"] == pytest.approx(0.099)
+    ctx = _ctx(scopes=scopes.hlo_scopes(text), device_ids=[0])
+    out = phases.phase_report(ctx)
+    assert out["window_s"] == pytest.approx(0.1)
     assert out["phases_s"]["update_mix"] == pytest.approx(0.02)
     assert out["flat_copy_pct"] == 0.0
     # one chip: fusion.1 at 10-30 ms on device 0
-    flops = 3 * tr["h"] * counts.model_flops_per_token(
-        cell.config["arch"], tr["seq_len"]) * tr["agents"] \
-        * tr["per_agent_batch"] * tr["seq_len"]
     assert out["grad_flops_pct"] == pytest.approx(
-        100 * flops / (0.02 * 197e12))
+        100 * 10 * 1e12 / (0.02 * 200e12))
+
+
+def test_phase_metric_readers():
+    """The readers of the two per-layer metrics are scopes.py's own."""
+    ctx = _ctx(scopes=_scoped())
+    assert _read("grad_flops_pct", ctx) == scopes.grad_flops_pct(ctx)
+    assert _read("flat_copy_pct", ctx) == scopes.flat_copy_pct(ctx)
+    assert _read("grad_flops_pct", ctx) > 0 < _read("flat_copy_pct", ctx)
+    assert _read("grad_flops_pct", _ctx()) is None
 
 
 @pytest.fixture(scope="module")
@@ -156,15 +160,24 @@ def _ops(text, kinds):
 
 
 def test_traced_run_keeps_text_and_trace(tiny_traced):
-    result, text, trace = tiny_traced
+    result, ctx = tiny_traced
     assert result["correct"], result["checks"]
-    assert "feddec." in text
-    assert {s.name for s in trace.spans} >= {"bench.dispatch",
-                                             "bench.loss_pull"}
+    assert "feddec." in ctx["text"]
+    assert ctx["scopes"] == scopes.hlo_scopes(ctx["text"])
+    assert {s.name for s in ctx["trace"].spans} >= {"bench.dispatch",
+                                                    "bench.loss_pull"}
+    cell = tiny_cell(CELL)
+    tr, arch = cell.traffic, cell.config["arch"]
+    assert ctx["flops_per_step"] == harness.reference_model(
+        cell).flops_per_token(arch, tr["seq_len"]) * tr["agents"] \
+        * tr["per_agent_batch"] * tr["seq_len"]
+    # the CPU's trace has no device plane: the phase readers find nothing
+    assert "mfu_pct" in result["metrics"]
+    assert set(result["metrics"]) <= {m["name"] for m in cell.per_layer}
 
 
 def test_tiny_fused_round_phases(tiny_traced):
-    _, text, _ = tiny_traced
+    text = tiny_traced[1]["text"]
     sc = scopes.hlo_scopes(text)
     assert set(sc.values()) >= {"unflatten", "grad", "flatten",
                                 "update_mix", "server"}
