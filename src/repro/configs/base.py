@@ -102,6 +102,7 @@ class ArchConfig:
 
     norm_eps: float = 1e-6
     tie_embeddings: bool = False
+    scale_embeddings: bool = True  # input embedding times sqrt(d_model)
     logit_softcap: float = 0.0
 
     param_dtype: Any = jnp.float32

@@ -26,7 +26,11 @@ from repro.configs.base import SSMConfig
 from repro.models import layers
 
 __all__ = ["init_mamba2", "mamba2_block", "init_mamba2_cache", "ssd_chunked",
-           "ssd_decode_step"]
+           "ssd_decode_step", "SSD_SCOPE"]
+
+# the named scope of the chunked scan in a block's forward (and so in its
+# backward and recomputation): the trace reads the scan's device time by it
+SSD_SCOPE = "ssm.ssd"
 
 
 # ---------------------------------------------------------------------------
@@ -73,10 +77,13 @@ def ssd_chunked(x: jax.Array, dt: jax.Array, a: jax.Array, b: jax.Array,
 
     # ---- intra-chunk: masked decay "attention" -----------------------------
     # decay[i,j] = exp(cum_i − cum_j) for i ≥ j (both inclusive cumsums ⇒
-    # contribution of step j's input to step i's output).
+    # contribution of step j's input to step i's output).  Above the
+    # diagonal diff is large and positive: it is masked to -inf before the
+    # exp, which would overflow there, and whose backward would then
+    # multiply inf by zero.
     diff = cum[:, :, :, None, :] - cum[:, :, None, :, :]    # (B,NC,L,L,H)
     mask = jnp.tril(jnp.ones((chunk, chunk), bool))
-    decay = jnp.where(mask[None, None, :, :, None], jnp.exp(diff), 0.0)
+    decay = jnp.exp(jnp.where(mask[None, None, :, :, None], diff, -jnp.inf))
     cb = jnp.einsum("bnid,bnjd->bnij", cc, bc)              # (B,NC,L,L)
     y_intra = jnp.einsum("bnij,bnijh,bnjhp->bnihp", cb, decay, xl)
 
@@ -180,8 +187,10 @@ def _causal_conv(xbc: jax.Array, w: jax.Array, bias: jax.Array,
 def mamba2_block(params: dict, x: jax.Array, cfg: SSMConfig, *,
                  cache: dict | None = None,
                  compute_dtype=jnp.bfloat16,
-                 use_pallas: bool = False) -> tuple[jax.Array, dict | None]:
-    """Apply one Mamba2 mixer.  x: (B, S, d) → (B, S, d)."""
+                 use_pallas: bool = False,
+                 eps: float = 1e-6) -> tuple[jax.Array, dict | None]:
+    """Apply one Mamba2 mixer.  x: (B, S, d) → (B, S, d); ``eps`` is the
+    gated RMSNorm's."""
     bsz, s, d = x.shape
     di = cfg.d_inner(d)
     nh = cfg.num_heads(d)
@@ -203,11 +212,13 @@ def mamba2_block(params: dict, x: jax.Array, cfg: SSMConfig, *,
     xh = xin.reshape(bsz, s, nh, cfg.head_dim)
 
     if cache is None:
-        if use_pallas:
-            from repro.kernels import ops as kops
-            y, _ = kops.ssd_scan(xh, dt, a, b, c, chunk=cfg.chunk_size)
-        else:
-            y, _ = ssd_chunked(xh, dt, a, b, c, chunk=min(cfg.chunk_size, s))
+        with jax.named_scope(SSD_SCOPE):
+            if use_pallas:
+                from repro.kernels import ops as kops
+                y, _ = kops.ssd_scan(xh, dt, a, b, c, chunk=cfg.chunk_size)
+            else:
+                y, _ = ssd_chunked(xh, dt, a, b, c,
+                                   chunk=min(cfg.chunk_size, s))
         new_cache = None
     else:
         y1, new_ssm = ssd_decode_step(cache["ssm"], xh[:, 0], dt[:, 0], a,
@@ -217,6 +228,6 @@ def mamba2_block(params: dict, x: jax.Array, cfg: SSMConfig, *,
 
     y = y + params["d_skip"].astype(y.dtype)[None, None, :, None] * xh
     y = y.reshape(bsz, s, di)
-    y = layers.rms_norm(params["norm"], y * jax.nn.silu(z))
+    y = layers.rms_norm(params["norm"], y * jax.nn.silu(z), eps)
     out = layers.dense(params["out_proj"], y, compute_dtype=compute_dtype)
     return out, new_cache

@@ -186,7 +186,8 @@ def apply_block(params: dict, x: jax.Array, positions: jax.Array,
         y, c = ssm_lib.mamba2_block(params["mixer"], h, cfg.ssm,
                                     cache=cache.get("self") if cache else None,
                                     compute_dtype=cdt,
-                                    use_pallas=(impl == "pallas"))
+                                    use_pallas=(impl == "pallas"),
+                                    eps=cfg.norm_eps)
         if c is not None:
             new_cache["self"] = c
         return x + y, (new_cache or None), aux
@@ -340,7 +341,8 @@ def init_model(key, cfg: ArchConfig) -> dict:
 def _embed_inputs(params, cfg: ArchConfig, batch: Batch) -> jax.Array:
     x = layers.embed(params["embed"], batch["tokens"],
                      compute_dtype=cfg.compute_dtype)
-    x = x * jnp.asarray(cfg.d_model ** 0.5, cfg.compute_dtype)
+    if cfg.scale_embeddings:
+        x = x * jnp.asarray(cfg.d_model ** 0.5, cfg.compute_dtype)
     if cfg.frontend == "vision" and "frontend_embeds" in batch:
         fe = batch["frontend_embeds"].astype(cfg.compute_dtype)
         npos = fe.shape[1]

@@ -148,7 +148,7 @@ class TestConfigIntegrity:
             "gemma3-12b": (48, 3840, 16, 8, 15360, 262144),
             "deepseek-v3-671b": (61, 7168, 128, 128, 18432, 129280),
             "mistral-large-123b": (88, 12288, 96, 8, 28672, 32768),
-            "mamba2-2.7b": (64, 2560, 1, 1, 0, 50280),
+            "mamba2-2.7b": (64, 2560, 1, 1, 0, 50288),
             "deepseek-v2-lite-16b": (27, 2048, 16, 16, 10944, 102400),
             "recurrentgemma-9b": (38, 4096, 16, 1, 12288, 256000),
             "qwen1.5-4b": (40, 2560, 20, 20, 6912, 151936),
