@@ -53,6 +53,8 @@ __all__ = ["FlatSpec", "FlatFedState", "make_flat_spec",
 GradFn = Callable[[Any, Any, jax.Array], tuple[jax.Array, Any]]
 LrFn = Callable[[jax.Array], jax.Array]
 
+LANES = 128  # a TPU vreg's lane width: the (n, D) buffer's tile is (n, 128)
+
 
 @dataclasses.dataclass(frozen=True)
 class FlatSpec:
@@ -69,6 +71,12 @@ class FlatSpec:
       d: total flat length D = Σ sizes.
       dtype: the buffer dtype (all leaves are cast into it on flatten and
         back to their original dtype on unflatten).
+
+    A leaf whose rows are not lane-aligned but whose per-agent size is
+    (``lane_dense_leaves``) moves between its own shape and its (n, S)
+    segment through ``_to_shape``'s lane-dense swap: a plain reshape of
+    such a leaf makes XLA:TPU copy it one agent at a time, in a ``while``
+    loop, into the buffer's (n, 128) tiles and back.
     """
 
     treedef: Any
@@ -82,6 +90,35 @@ class FlatSpec:
     @property
     def num_leaves(self) -> int:
         return len(self.shapes)
+
+    @property
+    def lane_dense_leaves(self) -> tuple[int, ...]:
+        """Indices of the leaves that move through the lane-dense swap:
+        the last dimension is not a multiple of ``LANES``, the per-agent
+        size is."""
+        return tuple(i for i, (shape, s) in enumerate(zip(self.shapes,
+                                                          self.sizes))
+                     if shape and shape[-1] % LANES and not s % LANES)
+
+    @property
+    def lane_dense_share(self) -> float:
+        """The lane-dense leaves' share of D."""
+        return sum(self.sizes[i] for i in self.lane_dense_leaves) / self.d
+
+    def _to_shape(self, i: int, x: jax.Array, shape: tuple) -> jax.Array:
+        """Leaf ``i``'s stacked values ``x`` (n, ...) → shape (n, *shape).
+
+        A lane-dense leaf is viewed as rows of 128 lanes, (n, S/128, 128),
+        and swapped to (S/128, n, 128), the order of the buffer's (n, 128)
+        tiles, behind an optimization barrier, then swapped back: XLA moves
+        it in one relayout pass.  Without the barrier the two swaps cancel
+        and the per-agent loop returns.  Values are moved, never computed.
+        """
+        n = x.shape[0]
+        if i in self.lane_dense_leaves:
+            v = jnp.swapaxes(x.reshape(n, -1, LANES), 0, 1)
+            x = jnp.swapaxes(jax.lax.optimization_barrier(v), 0, 1)
+        return x.reshape((n,) + shape)
 
     # -- single-agent (no leading n) ----------------------------------------
 
@@ -106,12 +143,12 @@ class FlatSpec:
         buffers, which stay f32 even when the parameter buffer is bf16).
         """
         leaves = self.treedef.flatten_up_to(stacked)
-        n = leaves[0].shape[0]
         dt = self.dtype if dtype is None else dtype
         with jax.named_scope("feddec.flatten"):
             return jnp.concatenate(
-                [jnp.asarray(l).astype(dt).reshape(n, -1)
-                 for l in leaves], axis=1)
+                [self._to_shape(i, jnp.asarray(l).astype(dt), (s,))
+                 for i, (l, s) in enumerate(zip(leaves, self.sizes))],
+                axis=1)
 
     def unflatten(self, buf: jax.Array, cast: bool = True) -> Any:
         """(n, D) buffer → stacked pytree of (n, ...) leaves.
@@ -123,13 +160,12 @@ class FlatSpec:
         2-layer, vocab-32768 tiny LM with 4 agents compiled for a v5e in
         200 s without the barrier and in 97 s with it, on a CPU host).
         """
-        n = buf.shape[0]
         with jax.named_scope("feddec.unflatten"):
             parts = [
-                buf[:, o:o + s].reshape((n,) + shape)
+                self._to_shape(i, buf[:, o:o + s], shape)
                 .astype(dt if cast else buf.dtype)
-                for o, s, shape, dt in zip(self.offsets, self.sizes,
-                                           self.shapes, self.dtypes)]
+                for i, (o, s, shape, dt) in enumerate(zip(
+                    self.offsets, self.sizes, self.shapes, self.dtypes))]
             parts = jax.lax.optimization_barrier(parts)
         return jax.tree.unflatten(self.treedef, parts)
 

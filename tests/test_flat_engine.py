@@ -159,3 +159,73 @@ class TestSpecAndConversion:
             FedDecConfig(mixing=cfg.mixing, gossip_impl="permute")
         with pytest.raises(ValueError, match="dense|none|pallas|sparse"):
             FedDecConfig(mixing=cfg.mixing, gossip_impl="bogus")
+
+
+def _reshape_flatten(spec, stacked, dtype):
+    """The plain route: every leaf reshaped to (n, S) in place."""
+    leaves = spec.treedef.flatten_up_to(stacked)
+    return jnp.concatenate(
+        [l.astype(dtype).reshape(l.shape[0], -1) for l in leaves], axis=1)
+
+
+def _reshape_unflatten(spec, buf):
+    n = buf.shape[0]
+    return [buf[:, o:o + s].reshape((n,) + shape).astype(dt)
+            for o, s, shape, dt in zip(spec.offsets, spec.sizes,
+                                       spec.shapes, spec.dtypes)]
+
+
+class TestLaneDenseRoute:
+    """Leaves whose rows are not lane-aligned but whose per-agent size is
+    move through FlatSpec's lane-dense swap; the buffer and the leaves are
+    bit-identical to the plain reshape's."""
+
+    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+    @pytest.mark.parametrize("n", [1, 2, 4, 8])
+    @pytest.mark.parametrize("shape,routed", [
+        ((32, 200), True), ((2, 16, 176), True),
+        ((24, 200), False)])  # S = 4,800: not whole rows of 128 lanes
+    def test_route_is_bit_identical(self, shape, routed, n, dtype):
+        key = jax.random.key(n)
+        tree = {"odd": jax.random.normal(key, (n,) + shape).astype(dtype),
+                "aligned": jax.random.normal(jax.random.fold_in(key, 1),
+                                             (n, 3, 256)),
+                "bias": jnp.arange(n * 5, dtype=jnp.float32).reshape(n, 5)}
+        spec = flat_lib.make_flat_spec_from_stacked(tree)
+        odd = sorted(tree).index("odd")  # leaves in sorted-key order
+        assert spec.lane_dense_leaves == ((odd,) if routed else ())
+        buf = jax.jit(spec.flatten)(tree)
+        np.testing.assert_array_equal(
+            np.asarray(buf), np.asarray(_reshape_flatten(spec, tree,
+                                                         spec.dtype)))
+        back = jax.tree.leaves(jax.jit(spec.unflatten)(buf))
+        for got, want, leaf in zip(back, _reshape_unflatten(spec, buf),
+                                   jax.tree.leaves(tree)):
+            assert got.dtype == leaf.dtype and got.shape == leaf.shape
+            np.testing.assert_array_equal(np.asarray(got, np.float32),
+                                          np.asarray(want, np.float32))
+            np.testing.assert_array_equal(np.asarray(got, np.float32),
+                                          np.asarray(leaf, np.float32))
+
+    @pytest.mark.parametrize("name,cut,routed,share", [
+        # the benchmark cells' cuts: Qwen's head (vocab 18,992) and
+        # Mamba-2's in_proj (10,576 columns) take the route
+        ("qwen1.5-4b", dict(num_layers=2, vocab_size=18_992),
+         ["['head']['w']"], 0.1900),
+        ("mamba2-2.7b", dict(num_layers=4, vocab_size=6_286),
+         ["['stack']['scan']['sub_0']['mixer']['in_proj']['w']"], 0.6120),
+        # Qwen's published vocabulary, 151,936, is lane-aligned: no leaf
+        ("qwen1.5-4b", dict(num_layers=2), [], 0.0)])
+    def test_route_picks_unaligned_leaves(self, name, cut, routed, share):
+        import dataclasses
+
+        from repro.configs import get_config
+        from repro.models import build_model
+
+        cfg = dataclasses.replace(get_config(name), **cut)
+        shapes = jax.eval_shape(build_model(cfg).init, jax.random.key(0))
+        spec = flat_lib.make_flat_spec(shapes)
+        paths = [jax.tree_util.keystr(p) for p, _ in
+                 jax.tree_util.tree_flatten_with_path(shapes)[0]]
+        assert [paths[i] for i in spec.lane_dense_leaves] == routed
+        assert spec.lane_dense_share == pytest.approx(share, abs=1e-4)

@@ -9,8 +9,9 @@ that is described and not attached, at the shapes ``chip_smoke.py`` trains:
 last tile), ring graph (ELL max degree 2), f32, each kernel at the D tile
 ``kernels.ops`` sizes for it from its VMEM budget; and the Qwen1.5-4B
 benchmark cell's fused update+mix at its own D = 255,864,320, to show
-that the budget's tile fits VMEM.  Nothing runs; a compile that passes is
-not a chip run.
+that the budget's tile fits VMEM; and ``FlatSpec.flatten``/``unflatten`` of
+a leaf whose rows are not lane-aligned, to show that no per-agent loop is
+left.  Nothing runs; a compile that passes is not a chip run.
 
 The topology is described inside a module-scoped fixture, never at import:
 only one process may load libtpu, and under pytest-xdist every worker
@@ -159,3 +160,27 @@ def test_kernel_compiles_for_v5e(one_chip, name):
     # the kernel streams the buffers in place: no padded (n, D) copy
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes < N * bd * 64, (name, mem)
+
+
+@pytest.mark.parametrize("direction", ["flatten", "unflatten"])
+def test_flat_spec_moves_unaligned_rows_in_one_pass(one_chip, direction):
+    """A leaf whose rows (1,000 columns) are not lane-aligned but whose
+    per-agent size is moves between its shape and the (n, D) buffer with
+    no ``while`` loop over agents: XLA:TPU moves such a leaf, reshaped in
+    place, into the buffer's (n, 128) tiles and back one agent at a time
+    (from a few million elements an agent: 2,560 rows do, 256 do not), and
+    FlatSpec's swap behind an optimization barrier keeps the loop out."""
+    from repro.core import flat as flat_lib
+
+    spec = flat_lib.make_flat_spec(
+        {"w": jax.ShapeDtypeStruct((2560, 1000), jnp.float32),
+         "b": jax.ShapeDtypeStruct((1000,), jnp.float32)})
+    assert spec.lane_dense_leaves == (1,)      # "w", after "b"
+    if direction == "flatten":
+        fn, arg = spec.flatten, {"w": _s(one_chip, (N, 2560, 1000)),
+                                 "b": _s(one_chip, (N, 1000))}
+    else:
+        fn, arg = spec.unflatten, _s(one_chip, (N, spec.d))
+    text = jax.jit(fn).lower(arg).compile().as_text()
+    assert f"feddec.{direction}" in text
+    assert not re.search(r"\bwhile\(", text), direction
