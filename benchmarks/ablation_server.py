@@ -23,7 +23,6 @@ import time
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
 from benchmarks import common
 from repro.core import feddec, theory, topology as topo

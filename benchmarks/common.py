@@ -1,4 +1,4 @@
-"""Shared benchmark utilities: timing, CSV emission, result paths, and the
+"""Shared benchmark utilities: CSV emission, result paths, and the
 linreg sweep-lattice setup shared by the figure scripts.
 
 The figure scripts (fig2_alpha / fig4_convergence / theory_check) all drive
@@ -12,8 +12,6 @@ from __future__ import annotations
 
 import argparse
 import os
-import time
-from typing import Callable
 
 RESULTS_DIR = os.path.join(os.path.dirname(__file__), "..", "results",
                            "benchmarks")
@@ -31,20 +29,6 @@ def write_csv(name: str, header: list[str], rows: list[tuple]) -> str:
         for r in rows:
             f.write(",".join(str(x) for x in r) + "\n")
     return path
-
-
-def time_fn(fn: Callable, *args, warmup: int = 2, iters: int = 10) -> float:
-    """Median wall-time per call in microseconds (blocks on jax outputs)."""
-    import jax
-    for _ in range(warmup):
-        jax.block_until_ready(fn(*args))
-    times = []
-    for _ in range(iters):
-        t0 = time.perf_counter()
-        jax.block_until_ready(fn(*args))
-        times.append(time.perf_counter() - t0)
-    times.sort()
-    return times[len(times) // 2] * 1e6
 
 
 def emit(name: str, us_per_call: float, derived: str) -> None:

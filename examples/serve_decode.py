@@ -2,9 +2,9 @@
 
 Instantiates the reduced variant of any assigned architecture, prefills a
 batch of prompts, and greedily decodes continuations using the same
-KV/state-cache machinery the decode_32k / long_500k dry-runs compile at
-production scale — including the O(1)-state sub-quadratic paths (mamba2,
-recurrentgemma) and MLA's compressed latent cache (deepseek).
+KV/state-cache machinery the full-size configs use — including the
+O(1)-state sub-quadratic paths (mamba2, recurrentgemma) and MLA's
+compressed latent cache (deepseek).
 
 Run:  PYTHONPATH=src python examples/serve_decode.py --arch mamba2-2.7b
 """

@@ -112,18 +112,6 @@ class ArchConfig:
     fed_agent_layout: str = "sharded"  # sharded (n=|agent axes|) | replicated
     fed_n_agents_replicated: int = 4   # agents PER POD for layout=replicated
 
-    # set automatically at lowering time when num_heads % tp != 0: QKV
-    # projections then constrain their weights to replicated (ZeRO-style
-    # gather-on-use) instead of partial-summing activations — see
-    # sharding._tp_preferences and launch/steps.py
-    attn_weight_gather: bool = False
-    # mesh axis carrying the activation batch dim (serving: 'data'; training
-    # leaves it None — the batch dim inside the per-agent vmap is unsharded)
-    batch_axis_name: str | None = None
-    # tensor-parallel axis name, set by launch.steps.adapt_for_mesh at
-    # lowering time; enables explicit head-/expert-sharding constraints in
-    # MLA and MoE (left None on hosts without the production mesh)
-    tp_axis_name: str | None = None
     # memory-efficient scan-over-query-chunks prefill (models.attention).
     # The 2-D sharded engine clears it: a lax.scan whose stacked ys cross
     # the partially-auto shard_map region is rejected by the SPMD

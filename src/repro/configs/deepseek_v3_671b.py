@@ -3,8 +3,7 @@
 61L, d_model 7168, 128 heads with MLA (kv_lora 512, q_lora 1536,
 qk 128 nope + 64 rope, v 128); MoE with 1 shared + 256 routed experts,
 top-8, expert d_ff 2048 (first 3 layers dense, d_ff 18432); vocab 129280.
-MTP (multi-token prediction) is implemented as an optional extra head —
-see ``repro.models.mtp`` — and is off in the dry-run shapes.
+MTP (multi-token prediction) is not implemented.
 
 671B params ⇒ federated agents cannot hold replicas: the framework uses the
 ``replicated`` agent layout (4 cross-silo agents, each agent's state

@@ -46,7 +46,7 @@ The int8 flat path fuses quantize→mix→dequantize into one Pallas streaming
 kernel (kernels/compress_mix.py) when ``gossip_impl='pallas'``.
 
 Cost model: :func:`repro.launch.analysis.compress_row_bytes` /
-``compressed_halo_cost_model``; measured: ``benchmarks/bench_compress.py``.
+``compressed_halo_cost_model``.
 """
 
 from __future__ import annotations

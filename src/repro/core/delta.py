@@ -45,8 +45,7 @@ file-backed payload is the encoded delta (numpy mirror of the codecs), so
 the 1e6-agent host store shrinks from O(n_total·D) to O(n_total·K) bytes.
 
 Cost model: :func:`repro.launch.analysis.delta_cost_model` (jax-free
-mirror of :func:`delta_store_bytes_per_row`); measured:
-``benchmarks/bench_delta.py``.
+mirror of :func:`delta_store_bytes_per_row`).
 """
 
 from __future__ import annotations
@@ -55,7 +54,6 @@ import dataclasses
 import json
 import os
 import tempfile
-from typing import Any
 
 import numpy as np
 

@@ -29,8 +29,8 @@ far materializes the full ``(n_agents, D)`` buffer on device, capping n at
   collision probability is ~cohort²/n_total and the pipeline stays full.
 
 Peak device memory is bounded by the cohort — two (cohort, D) buffers plus
-two cohort-sized ELL edge tables — **independent of n_total** (the flat
-invariant pinned by benchmarks/BENCH_population.json).
+two cohort-sized ELL edge tables — **independent of n_total**
+(``launch/analysis.population_cost_model``).
 
 Bit-identity: with ``n_total == cohort_size`` and uniform sampling the
 cohort is the identity slice every round, the induced subgraph is the full

@@ -9,7 +9,7 @@ learnable structure.
 
 The pipeline is an infinite, deterministic, jax-PRNG-driven stream — every
 batch is reproducible from (seed, step) with no host state, so the training
-loop stays pure and the dry-run can shard the same pipeline.
+loop stays pure.
 """
 
 from __future__ import annotations

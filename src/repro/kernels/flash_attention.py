@@ -14,8 +14,7 @@ BlockSpec geometry: Q/O tiles are (1, 1, 1, BLOCK_Q, head_dim) over a
 (B, KV, G, S, hd) view — BLOCK_Q a multiple of the 8-sublane f32 tile and
 head_dim ∈ {64, 128, 256} a lane multiple.  K/V are delivered whole per
 (b, kv) program (S ≤ ~8k fits VMEM at bf16; longer sequences would stream
-via async HBM copies — noted, not needed for the validated shapes since the
-512-way dry-run shards S per device well below that).
+via async HBM copies — noted, not needed for the validated shapes).
 
 Numerics match ref.flash_attention_ref to ~1e-2 (bf16) / 1e-5 (f32);
 interpret=True executes the same kernel body on CPU for the test sweep.

@@ -1,181 +1,25 @@
-"""Post-compile analysis: memory, FLOPs, and collective-byte accounting.
+"""Exact byte counts of the engines: analytic cost models the tests check.
 
-``cost_analysis()`` gives HLO FLOPs / bytes; collective traffic is NOT in
-there, so we parse the post-SPMD optimized HLO and sum the *output* sizes of
-every communication op (all-gather / all-reduce / reduce-scatter /
-all-to-all / collective-permute).  Output-size is the standard convention
-for per-device collective bytes moved (all-reduce moves ~2× in a ring, which
-we report separately as an effective factor).
-
-Roofline constants (TPU v5e, per chip): 197 TFLOP/s bf16, 819 GB/s HBM,
-~50 GB/s/link ICI (DESIGN §8).
+Each model counts what one engine moves per step or per round — buffer
+passes, collective payloads, host-store rows — from shapes alone, so a test
+can hold the engine's layout to it without a device.  The ``pred_us``
+columns are roofline predictions at the constants below (TPU v5e, per
+chip), not measurements.
 """
 
 from __future__ import annotations
 
-import dataclasses
-import re
-from typing import Any
-
-import numpy as np
-
-from repro.launch import hlo_analysis
-
-__all__ = ["PEAK_FLOPS", "HBM_BW", "ICI_BW", "H2D_BW", "CollectiveStats",
-           "parse_collectives", "roofline_terms", "RooflineReport",
-           "dtype_bytes", "gossip_cost_model", "sharded_gossip_cost_model",
-           "mesh2d_cost_model",
-           "sweep_cost_model", "sharded_sweep_cost_model",
-           "population_cost_model", "compress_row_bytes",
-           "compressed_halo_cost_model", "COMPRESS_SCHEMES",
-           "delta_row_bytes", "delta_cost_model", "roundfuse_cost_model",
-           "hlo_analysis"]
+__all__ = ["PEAK_FLOPS", "HBM_BW", "ICI_BW", "H2D_BW",
+           "sharded_gossip_cost_model", "mesh2d_cost_model",
+           "sweep_cost_model", "population_cost_model",
+           "compress_row_bytes", "compressed_halo_cost_model",
+           "COMPRESS_SCHEMES", "delta_row_bytes", "delta_cost_model",
+           "roundfuse_cost_model"]
 
 PEAK_FLOPS = 197e12   # bf16 per chip
 HBM_BW = 819e9        # bytes/s per chip
 ICI_BW = 50e9         # bytes/s per link
 H2D_BW = 16e9         # bytes/s host↔device (PCIe-class; population streaming)
-
-_DTYPE_BYTES = {
-    "pred": 1, "s4": 1, "u4": 1, "s8": 1, "u8": 1,
-    "s16": 2, "u16": 2, "bf16": 2, "f16": 2,
-    "s32": 4, "u32": 4, "f32": 4,
-    "s64": 8, "u64": 8, "f64": 8, "c64": 8, "c128": 16,
-}
-
-_COLL_KINDS = ("all-gather", "all-reduce", "reduce-scatter", "all-to-all",
-               "collective-permute")
-
-# e.g.:  %all-gather.3 = bf16[4,1024,512]{2,1,0} all-gather(...)
-_OP_RE = re.compile(
-    r"=\s*(?:\(([^)]*)\)|(\w+)\[([\d,]*)\][^ ]*)\s+"
-    r"(all-gather|all-reduce|reduce-scatter|all-to-all|collective-permute)"
-    r"(?:-start)?\(")
-_TUPLE_ELT_RE = re.compile(r"(\w+)\[([\d,]*)\]")
-
-
-def dtype_bytes(name: str) -> int:
-    return _DTYPE_BYTES.get(name, 4)
-
-
-def _shape_bytes(dtype: str, dims: str) -> int:
-    if not dims:
-        return dtype_bytes(dtype)
-    n = int(np.prod([int(d) for d in dims.split(",") if d]))
-    return n * dtype_bytes(dtype)
-
-
-@dataclasses.dataclass
-class CollectiveStats:
-    counts: dict[str, int]
-    bytes_by_kind: dict[str, int]
-
-    @property
-    def total_bytes(self) -> int:
-        return sum(self.bytes_by_kind.values())
-
-    def summary(self) -> str:
-        parts = [f"{k}:{self.counts[k]}×/{self.bytes_by_kind[k]/1e6:.1f}MB"
-                 for k in sorted(self.counts) if self.counts[k]]
-        return " ".join(parts) or "none"
-
-
-def parse_collectives(hlo_text: str) -> CollectiveStats:
-    """Sum per-device output bytes of every collective in optimized HLO."""
-    counts = {k: 0 for k in _COLL_KINDS}
-    nbytes = {k: 0 for k in _COLL_KINDS}
-    for m in _OP_RE.finditer(hlo_text):
-        tuple_body, dtype, dims, kind = m.groups()
-        if tuple_body is not None:
-            size = sum(_shape_bytes(dt, dm)
-                       for dt, dm in _TUPLE_ELT_RE.findall(tuple_body))
-        else:
-            size = _shape_bytes(dtype, dims)
-        counts[kind] += 1
-        nbytes[kind] += size
-    return CollectiveStats(counts=counts, bytes_by_kind=nbytes)
-
-
-@dataclasses.dataclass
-class RooflineReport:
-    name: str
-    chips: int
-    hlo_flops: float            # total across devices (cost_analysis × chips)
-    hlo_bytes: float
-    collective_bytes: float     # per-device sum over ops
-    model_flops: float          # analytic 6·N·D (or 2·N·D decode)
-    compute_s: float
-    memory_s: float
-    collective_s: float
-
-    @property
-    def dominant(self) -> str:
-        terms = {"compute": self.compute_s, "memory": self.memory_s,
-                 "collective": self.collective_s}
-        return max(terms, key=terms.get)
-
-    @property
-    def useful_flops_ratio(self) -> float:
-        return self.model_flops / self.hlo_flops if self.hlo_flops else 0.0
-
-    def row(self) -> dict[str, Any]:
-        return {
-            "name": self.name, "chips": self.chips,
-            "hlo_flops": self.hlo_flops, "hlo_bytes": self.hlo_bytes,
-            "collective_bytes": self.collective_bytes,
-            "model_flops": self.model_flops,
-            "compute_s": self.compute_s, "memory_s": self.memory_s,
-            "collective_s": self.collective_s,
-            "dominant": self.dominant,
-            "useful_ratio": self.useful_flops_ratio,
-        }
-
-
-def gossip_cost_model(*, n_agents: int, d: int, num_leaves: int,
-                      num_directed_edges: int, param_bytes: int = 4,
-                      dispatch_us: float = 5.0) -> dict[str, dict]:
-    """Analytic per-gossip-step cost of every impl × state layout.
-
-    The gossip contraction Y = W X (X the stacked (n, D) parameters) is
-    bandwidth-bound for small n (2n FLOP per ``param_bytes`` streamed is far
-    below the ridge point) and compute-bound once n² FLOPs dominate — which
-    is exactly the regime split the flat engine's impls target:
-
-      * ``tree_dense``  — leaf-wise einsum: streams X once per leaf AND
-        materialises an f32 upcast of each non-f32 leaf (2× read tax),
-        plus one dispatch per leaf inside the scan body;
-      * ``flat_dense``  — one whole-buffer einsum: same upcast tax, one
-        dispatch, no per-leaf padding;
-      * ``flat_pallas`` — one kernel call: X streams through VMEM exactly
-        once with the cast fused (no upcast materialisation), W resident;
-      * ``flat_sparse`` — gather + segment_sum over the CSR edge list:
-        reads |E| rows instead of computing n² dot products — the FLOP
-        term drops from 2n²D to 2|E|D, which is what survives n ≳ 256.
-
-    Returns {impl: {bytes, flops, dispatches, pred_us}} with pred_us =
-    max(memory, compute) + dispatch overhead at the module constants
-    (HBM_BW, PEAK_FLOPS; dispatch_us per dispatch — host-side, so it
-    vanishes inside a fused scan but bounds the per-step executor).
-    """
-    n, dd, b = n_agents, float(d), param_bytes
-    stream = 2.0 * n * dd * b                 # read X + write Y once
-    upcast = 2.0 * n * dd * 4 if b != 4 else 0.0  # f32 temp write+read
-    dense_flops = 2.0 * n * n * dd
-    sparse_flops = 2.0 * num_directed_edges * dd
-    sparse_bytes = (num_directed_edges + 2.0 * n) * dd * b  # gather+own+Y
-
-    def entry(bytes_, flops, dispatches):
-        pred = max(bytes_ / HBM_BW, flops / PEAK_FLOPS) * 1e6 \
-            + dispatches * dispatch_us
-        return {"bytes": bytes_, "flops": flops, "dispatches": dispatches,
-                "pred_us": pred}
-
-    return {
-        "tree_dense": entry(stream + upcast, dense_flops, num_leaves),
-        "flat_dense": entry(stream + upcast, dense_flops, 1),
-        "flat_pallas": entry(stream, dense_flops, 1),
-        "flat_sparse": entry(sparse_bytes, sparse_flops, 1),
-    }
 
 
 def sharded_gossip_cost_model(*, n_agents: int, d: int, n_shards: int,
@@ -194,8 +38,7 @@ def sharded_gossip_cost_model(*, n_agents: int, d: int, n_shards: int,
       * ``sparse`` — the ppermute halo: ``num_halo_rounds`` block exchanges
         of n_local·D bytes per device, i.e. traffic scales with the
         *quotient* degree (the graph's cut), not with n.  For a ring over
-        contiguous blocks this is 2 rounds total at any scale — the
-        weak-scaling regime bench_sharded.py measures.
+        contiguous blocks this is 2 rounds total at any scale.
 
     ``ideal_cut_edge_bytes`` is the graph-theoretic floor (one row of D per
     directed cut edge, summed over devices): the halo moves whole blocks, so
@@ -252,8 +95,7 @@ def mesh2d_cost_model(*, n_agents: int, d: int, n_agent_shards: int,
     device owns n/A agent rows × D/M columns, so
 
       * ``state_bytes_per_device = n/A · D/M · param_bytes`` — exact, the
-        A·M-way memory scaling the 2-D mesh buys (BENCH_mesh2d.json
-        measures it from ``addressable_shards``);
+        A·M-way memory scaling the 2-D mesh buys;
       * agent-axis gossip bytes are the 1-D engine's formulas evaluated on
         the D/M column slice each device owns — dense psum_scatter moves
         ``(A−1)/A · n · D/M · b``, the ppermute halo
@@ -313,17 +155,16 @@ def sweep_cost_model(*, r_runs: int, n_agents: int, d: int,
     program; the per-run baseline (the pre-sweep figure-driver / train-loop
     pattern) dispatches one fused H-step engine call **per run per server
     window** — R·(T/H) dispatch + host-sync round-trips per trajectory.
-    Per-step device *work* is identical (R × the single-run bytes/FLOPs —
-    ``gossip_cost_model`` per impl, R×); what the batch removes is the
-    fixed per-dispatch cost, which dominates when the per-run tensors are
-    tiny (the figure regime: n=20, D=25).
+    Per-step device *work* is identical (R × the single-run bytes/FLOPs);
+    what the batch removes is the fixed per-dispatch cost, which dominates
+    when the per-run tensors are tiny (the figure regime: n=20, D=25).
 
-    Returns the exact columns the regression guard pins:
+    Returns:
       * ``state_bytes``       — R·n·D·b·(1 + opt_slots + residual), the
-        resident sweep state (the dryrun memory prediction);
+        resident sweep state;
       * ``step_stream_bytes`` — 2·R·n·D·b, one read+write pass over the
         lattice buffer per step (the local-update floor; gossip adds its
-        impl term from ``gossip_cost_model`` × R);
+        impl term, R× the single run's);
       * ``dispatches_loop``   — R·(T/H) (one engine call per run per
         window; R when T/H is unknown) vs ``dispatches_sweep`` = 1;
       * ``dispatch_overhead_us_saved`` — (dispatches_loop − 1)·dispatch_us
@@ -347,68 +188,6 @@ def sweep_cost_model(*, r_runs: int, n_agents: int, d: int,
     return out
 
 
-def sharded_sweep_cost_model(*, r_runs: int, n_agents: int, d: int,
-                             n_shards: int, num_halo_rounds: int,
-                             t_steps: int | None = None, h: int | None = None,
-                             param_bytes: int = 4, opt_slots: int = 0,
-                             residual: bool = False,
-                             dispatch_us: float = 5.0) -> dict:
-    """Analytic cost of the composed sharded-sweep engine (R runs × s shards).
-
-    The composition (repro.core.engine.make_sharded_sweep_round) lowers the
-    whole (R, n_agents, D) lattice with the agent dim block-sharded over
-    ``n_shards`` devices: each device carries an (R, n_local, D) block and
-    the entire T-step scan runs inside one shard_map — one program for the
-    full figure lattice.  Relative to the unsharded sweep engine
-    (``sweep_cost_model``) every per-device term shrinks by n_shards and a
-    collective term appears, which splits by gossip impl exactly as in
-    ``sharded_gossip_cost_model`` but with every payload R× wider (the run
-    axis rides along in each psum_scatter / ppermute block):
-
-      * ``state_bytes_per_device``        — R·n_local·D·b·slots, the
-        resident lattice block (slots = 1 + opt_slots + residual);
-      * ``step_stream_bytes_per_device``  — 2·R·n_local·D·b, one
-        read+write pass over the block per step (the local-update floor);
-      * ``dense_collective_bytes``        — (s−1)/s·R·n·D·b per device per
-        gossip step (the ring psum_scatter over the R-wide partials);
-      * ``halo_collective_bytes``         — rounds·R·n_local·D·b per device
-        per gossip step (the union-quotient ppermute schedule: the halo
-        count comes from the OR of the R run graphs, so it is the max over
-        runs, not the sum);
-      * ``dispatches_loop``               — R·(T/H) engine calls for the
-        per-run loop vs ``dispatches_sweep`` = 1 (the whole lattice is one
-        dispatch even sharded).
-    """
-    n, dd, b, s = n_agents, float(d), param_bytes, n_shards
-    if n % s:
-        raise ValueError(f"n_agents={n} must be divisible by "
-                         f"n_shards={s}")
-    n_local = n // s
-    slots = 1 + opt_slots + (1 if residual else 0)
-    state_blk = float(r_runs * n_local * dd * b * slots)
-    step_stream = 2.0 * r_runs * n_local * dd * b
-    dense_coll = (s - 1) / s * r_runs * n * dd * b if s > 1 else 0.0
-    halo_coll = num_halo_rounds * r_runs * n_local * dd * b if s > 1 else 0.0
-    n_windows = max(1, t_steps // h) if t_steps and h else 1
-    disp_loop = r_runs * n_windows
-    out = {
-        "r_runs": r_runs,
-        "n_shards": s,
-        "n_local": n_local,
-        "state_bytes_per_device": state_blk,
-        "step_stream_bytes_per_device": step_stream,
-        "dense_collective_bytes": dense_coll,
-        "halo_collective_bytes": halo_coll,
-        "num_halo_rounds": int(num_halo_rounds),
-        "dispatches_loop": disp_loop,
-        "dispatches_sweep": 1,
-        "dispatch_overhead_us_saved": (disp_loop - 1) * dispatch_us,
-    }
-    if t_steps is not None:
-        out["t_steps"] = int(t_steps)
-    return out
-
-
 def population_cost_model(*, n_total: int, cohort_size: int, d: int,
                           max_degree: int, h: int, param_bytes: int = 4,
                           idx_bytes: int = 4, counter_bytes: int = 8,
@@ -417,11 +196,10 @@ def population_cost_model(*, n_total: int, cohort_size: int, d: int,
 
     The population engine (repro.core.population) holds the (n_total, D)
     row store on the host (memmap) and streams one cohort per round, so
-    **every device-side term below depends only on the cohort** — the flat
-    peak-memory invariant the regression guard pins across
-    n_total ∈ {1e4, 1e5, 1e6}.
+    **every device-side term below depends only on the cohort** — the
+    peak device memory stays flat as n_total grows.
 
-    Returns the exact columns the regression guard recomputes:
+    Returns:
       * ``host_store_bytes``       — n_total·(D·b + counter_bytes): the
         memmap rows + per-agent last-participation counters (host only);
       * ``upload_bytes_round`` / ``writeback_bytes_round`` — cohort·D·b
@@ -490,8 +268,7 @@ def delta_cost_model(*, n_total: int, d: int, delta: str,
     The delta store (repro.core.delta.DeltaStore) replaces the population
     engine's dense (n_total, D) memmap with one shared base row plus
     per-agent encoded payloads, so the host store shrinks from
-    O(n_total·D) to O(n_total·K).  Returns the exact columns the
-    regression guard recomputes:
+    O(n_total·D) to O(n_total·K).  Returns:
 
       * ``delta_row_bytes``   — encoded payload bytes per agent (also the
         gossip wire bytes of the delta-encoded exchange);
@@ -552,9 +329,6 @@ def roundfuse_cost_model(*, n_agents: int, d: int, optimizer: str = "sgd",
     n_local block, and interior compute hides the in-flight rounds.
     ``predicted_overlap_fraction`` = min(1, interior stream time / halo
     time) at the module roofline constants.
-
-    Returns the exact columns ``check_regression.check_roundfuse_doc``
-    recomputes.
     """
     if optimizer not in ("sgd", "momentum"):
         raise ValueError(f"roundfuse_cost_model covers sgd|momentum "
@@ -639,8 +413,8 @@ def compressed_halo_cost_model(*, n_agents: int, d: int, n_shards: int,
     (n_local, D) block per ppermute round, so per-device collective bytes
     are ``num_halo_rounds · n_local · compress_row_bytes(scheme)`` — the
     dense psum_scatter path is compression-oblivious (f32 partial sums) and
-    is not modelled here.  ``payload_ratio_vs_f32`` is the column CI's
-    regression guard pins (int8 ≈ 0.25 ≤ 0.30 at any realistic D).
+    is not modelled here.  ``payload_ratio_vs_f32`` is int8 ≈ 0.25 at any
+    realistic D.
     """
     n_local = n_agents // n_shards
     f32_row = float(d * param_bytes)
@@ -655,24 +429,3 @@ def compressed_halo_cost_model(*, n_agents: int, d: int, n_shards: int,
             "pred_us": coll / ICI_BW * 1e6,
         }
     return out
-
-
-def roofline_terms(*, name: str, chips: int, per_device_flops: float,
-                   per_device_bytes: float, collective_bytes: float,
-                   model_flops: float) -> RooflineReport:
-    """Three roofline terms in seconds (per step), per DESIGN §8.
-
-    cost_analysis reports per-device numbers for SPMD modules; we scale
-    FLOPs back to cluster totals for the useful-ratio but keep the time
-    terms per-device (they are what bound the step).
-    """
-    return RooflineReport(
-        name=name, chips=chips,
-        hlo_flops=per_device_flops * chips,
-        hlo_bytes=per_device_bytes * chips,
-        collective_bytes=collective_bytes,
-        model_flops=model_flops,
-        compute_s=per_device_flops / PEAK_FLOPS,
-        memory_s=per_device_bytes / HBM_BW,
-        collective_s=collective_bytes / ICI_BW,
-    )

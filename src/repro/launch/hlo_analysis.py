@@ -56,17 +56,6 @@ _MATERIALIZING = (
     "cholesky", "triangular-solve",
 ) + _COLL_KINDS
 
-_CHEAP = {"get-tuple-element", "tuple", "parameter", "constant", "bitcast",
-          "after-all", "partition-id", "replica-id", "custom-call",
-          "bitcast-convert", "while", "conditional", "call", "convert",
-          "compare", "add", "subtract", "multiply", "divide", "select",
-          "maximum", "minimum", "exponential", "tanh", "negate", "and",
-          "or", "not", "xor", "abs", "sign", "floor", "ceil", "log",
-          "rsqrt", "sqrt", "power", "remainder", "clamp", "shift-left",
-          "shift-right-logical", "shift-right-arithmetic", "rng",
-          "optimization-barrier", "domain", "send", "recv", "infeed",
-          "outfeed"}
-
 _SHAPE_RE = re.compile(r"(\w+)\[([\d,]*)\]")
 _COMP_START_RE = re.compile(
     r"^(?:ENTRY\s+)?%?([\w.\-_]+)\s*\(.*->.*\{\s*$")
@@ -196,30 +185,6 @@ class HloCosts:
         default_factory=lambda: {k: 0 for k in _COLL_KINDS})
     collective_bytes_by_kind: dict = dataclasses.field(
         default_factory=lambda: {k: 0.0 for k in _COLL_KINDS})
-    # profile: heaviest individual ops, (weighted_bytes, kind, shape, origin)
-    top_traffic: list = dataclasses.field(default_factory=list)
-    top_collectives: list = dataclasses.field(default_factory=list)
-
-    def profile(self, n: int = 12) -> str:
-        """Human-readable hot-op report — the dry-run 'profiler' output."""
-        lines = [f"TOTAL flops={self.flops:.3e} "
-                 f"traffic={self.traffic_bytes / 1e9:.1f}GB "
-                 f"coll={self.collective_bytes / 1e9:.1f}GB",
-                 "-- top traffic ops (weighted bytes × trips) --"]
-        for b, kind, ty, org in self.top_traffic[:n]:
-            lines.append(f"  {b / 1e9:7.2f}GB  {kind:22s} {ty[:42]:42s} {org[-70:]}")
-        lines.append("-- top collectives --")
-        for b, kind, ty, org in self.top_collectives[:n]:
-            lines.append(f"  {b / 1e9:7.2f}GB  {kind:22s} {ty[:42]:42s} {org[-70:]}")
-        return "\n".join(lines)
-
-    def summary(self) -> str:
-        cs = " ".join(
-            f"{k}:{self.collective_counts[k]}x/"
-            f"{self.collective_bytes_by_kind[k] / 1e6:.0f}MB"
-            for k in _COLL_KINDS if self.collective_counts[k])
-        return (f"flops={self.flops:.3e} traffic={self.traffic_bytes:.3e}B "
-                f"coll={self.collective_bytes:.3e}B [{cs or 'none'}]")
 
 
 def analyze_hlo(text: str, entry: str | None = None) -> HloCosts:
@@ -293,22 +258,10 @@ def analyze_hlo(text: str, entry: str | None = None) -> HloCosts:
                 costs.collective_counts[kind] += 1
                 costs.collective_bytes_by_kind[kind] += mult * out_b
                 costs.collective_bytes += mult * out_b
-                om = _META_RE.search(op.rest)
-                costs.top_collectives.append(
-                    (mult * out_b, kind, op.type_str.split("{")[0],
-                     om.group(1) if om else ""))
             if op.kind in _MATERIALIZING or op.kind.endswith("-start"):
                 _, out_b = _shape_elems_bytes(op.type_str)
-                w = mult * (out_b + _operand_bytes(op, comp))
-                costs.traffic_bytes += w
-                om = _META_RE.search(op.rest)
-                costs.top_traffic.append(
-                    (w, op.kind, op.type_str.split("{")[0],
-                     om.group(1) if om else ""))
-    costs.top_traffic.sort(key=lambda t: -t[0])
-    costs.top_traffic = costs.top_traffic[:64]
-    costs.top_collectives.sort(key=lambda t: -t[0])
-    costs.top_collectives = costs.top_collectives[:64]
+                costs.traffic_bytes += mult * (out_b
+                                               + _operand_bytes(op, comp))
     return costs
 
 

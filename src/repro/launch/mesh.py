@@ -1,12 +1,7 @@
-"""Production mesh construction.
+"""Device meshes for the agent-sharded flat engines.
 
-``make_production_mesh`` is a FUNCTION (not a module-level constant) so that
-importing this module never touches jax device state — the 512-device
-host-platform override in dryrun.py must be set before the first jax call.
-
-Mesh shapes (TPU v5e):
-  single-pod : (16, 16)    axes ('data', 'model')   = 256 chips
-  multi-pod  : (2, 16, 16) axes ('pod', 'data', 'model') = 512 chips
+Meshes are built by FUNCTIONS, never as module-level constants, so that
+importing this module touches no device state.
 """
 
 from __future__ import annotations
@@ -14,8 +9,7 @@ from __future__ import annotations
 import jax
 from jax.sharding import AxisType
 
-__all__ = ["make_production_mesh", "make_host_mesh", "make_agent_mesh",
-           "make_fed_mesh"]
+__all__ = ["make_agent_mesh", "make_fed_mesh"]
 
 
 def _auto(n: int) -> tuple[AxisType, ...]:
@@ -23,17 +17,6 @@ def _auto(n: int) -> tuple[AxisType, ...]:
     ``with_sharding_constraint`` takes plain PartitionSpecs (``jax.make_mesh``
     defaults to Explicit axes, which carry shardings in the types)."""
     return (AxisType.Auto,) * n
-
-
-def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
-    shape = (2, 16, 16) if multi_pod else (16, 16)
-    axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, _auto(len(shape)))
-
-
-def make_host_mesh(data: int = 1, model: int = 1) -> jax.sharding.Mesh:
-    """Small mesh over the actually-present devices (tests / examples)."""
-    return jax.make_mesh((data, model), ("data", "model"), _auto(2))
 
 
 def make_agent_mesh(n_shards: int,

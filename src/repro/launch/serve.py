@@ -1,10 +1,10 @@
 """Serving driver: batched prefill + greedy decode with KV/state caches.
 
-The host-scale counterpart of the decode dry-run: builds the model, runs a
-full prefill to populate the caches (token-by-token here — numerically the
-same cache state the chunked prefill would produce), then decodes new tokens
-one step at a time.  Works for every assigned architecture, including the
-sub-quadratic ones whose caches are O(1) in sequence length.
+Builds the model, runs a full prefill to populate the caches (token-by-token
+here — numerically the same cache state the chunked prefill would produce),
+then decodes new tokens one step at a time.  Works for every assigned
+architecture, including the sub-quadratic ones whose caches are O(1) in
+sequence length.
 
 Two entry points:
 
@@ -15,7 +15,7 @@ Two entry points:
     parameterization of repro.core.delta).  The deltas are applied with one
     vmapped unflatten and the whole batch runs through ONE vmapped decode
     step per token — B compiled dispatches per token (the naive per-agent
-    loop) collapse to one.  Benchmarked in benchmarks/bench_delta.py.
+    loop) collapse to one.
 
 The compiled decode step is cached per (model, long_variant) — repeated
 ``generate()`` calls with same-shaped requests reuse the compiled fn
@@ -146,9 +146,9 @@ def generate_personalized(model, flat_spec, base_row: jax.Array,
     deltas (decode a DeltaStore gather / delta-codec payload first;
     ``None`` serves the bare base to every request).  The per-request
     parameter trees are materialized with one whole-buffer add + unflatten,
-    and each decoded token is ONE vmapped dispatch over the request axis —
-    the naive alternative (B sequential ``generate`` calls with B full
-    parameter sets) is what benchmarks/bench_delta.py compares against.
+    and each decoded token is ONE vmapped dispatch over the request axis,
+    where the naive alternative makes B sequential ``generate`` calls with
+    B full parameter sets.
 
     Decoder-only path (no enc_out): personalized serving targets the
     FedDec agent checkpoints, which are decoder-only throughout.

@@ -1,10 +1,9 @@
 """ShapeDtypeStruct stand-ins for every model input (no device allocation).
 
-``input_specs(cfg, shape, axes)`` returns the exact pytree the train/serve
-step consumes, as ShapeDtypeStructs — weak-type-correct and shardable, so
-``jax.jit(...).lower(**specs)`` compiles the full production shape without
-materialising a single array.  ``concrete_batch`` builds small real batches
-for tests/examples from the same schema (one source of truth for shapes).
+``batch_schema`` is the one source of truth for a batch's leaves and
+shapes; ``train_batch_specs`` / ``decode_batch_specs`` return it as
+ShapeDtypeStructs, and ``concrete_batch`` builds small real batches from
+it for tests and examples.
 """
 
 from __future__ import annotations

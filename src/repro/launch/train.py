@@ -2,9 +2,8 @@
 
 Runs Algorithm 1 on any assigned architecture (reduced or full config) over
 synthetic heterogeneous per-agent data streams, with checkpointing and an
-optional FedAvg control arm.  On the production mesh this is launched with
-the same Lowerables the dry-run compiles; on the host (CPU/1 device) it runs
-the smoke-scale configs directly — same code path, smaller shapes.
+optional FedAvg control arm.  On the host (CPU/1 device) it runs the
+smoke-scale configs — the same code path as on the chip, smaller shapes.
 
 Example (host scale):
   PYTHONPATH=src python -m repro.launch.train --arch qwen1.5-4b --smoke \\
@@ -398,9 +397,8 @@ def population_loop(cfg: ArchConfig, fed: FedConfig, *, n_total: int,
     ``(store, loss_history)`` — the store holds every agent's final rows.
 
     The per-agent LM data table is (n_total, vocab), so LM population runs
-    target n_total ≲ 1e5; the 1e6 regime is exercised with linreg-scale D
-    by benchmarks/bench_population.py, where the data stream is generated
-    per cohort.
+    target n_total ≲ 1e5; the 1e6 regime needs linreg-scale D, with the
+    data stream generated per cohort.
     """
     if steps % fed.h:
         raise ValueError(f"population mode runs whole H-step rounds; "

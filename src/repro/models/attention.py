@@ -6,7 +6,7 @@ mistral, nemotron, recurrentgemma's local-attn blocks, seamless, qwen2-vl):
   * prefill (``cache=None``): full-sequence causal attention, optionally
     windowed; the compute can route through the Pallas flash kernel
     (``impl='pallas'``) or the XLA einsum path (``impl='xla'``, numerically
-    identical, used on CPU and in the 512-device dry-run).
+    identical, used on CPU).
   * decode (``cache`` given): one query token against a (possibly rolling)
     cache.  The cache stores per-slot absolute positions so the same masking
     logic covers full caches, sliding windows and the ring buffer used by the
@@ -99,8 +99,7 @@ def _attend_block(q, k, v, qpos, kpos, *, scale, window, causal):
 
 
 def _chunked_prefill(q, k, v, qpos, kpos, *, scale, window, causal,
-                     chunk: int = 512, head_axis: str | None = None,
-                     batch_axis: str | None = None) -> jax.Array:
+                     chunk: int = 512) -> jax.Array:
     """Memory-efficient prefill: scan over query chunks (O(C·T) live scores).
 
     The XLA analogue of the Pallas flash kernel's outer loop — keeps the
@@ -128,30 +127,13 @@ def _chunked_prefill(q, k, v, qpos, kpos, *, scale, window, causal,
     qc = q.reshape(b, nc, chunk, h, hd).swapaxes(0, 1)      # (NC,B,C,H,hd)
     t = k.shape[1]
 
-    def _pin(x, spec):
-        return jax.lax.with_sharding_constraint(x, spec)
-
-    if head_axis is not None:
-        # pin head-parallel attention through the scan: constraining only
-        # the pre-chunk q/k/v is not enough — SPMD re-shards the scan xs
-        # and picks head_dim-contracting parallelism for the score einsum
-        # (§Perf iteration C1/C3)
-        from jax.sharding import PartitionSpec as _P
-        qc = _pin(qc, _P(None, batch_axis, None, head_axis, None))
-        k = _pin(k, _P(batch_axis, None, head_axis, None))
-        v = _pin(v, _P(batch_axis, None, head_axis, None))
-
     @jax.checkpoint
     def attend_chunk(qi, i):
         q0 = i * chunk
         qpos_i = (q0 + jnp.arange(chunk))[None]             # (1, C)
         kpos_i = jnp.arange(t)[None]                        # (1, T)
-        out = _attend_block(qi, k, v, qpos_i, kpos_i, scale=scale,
-                            window=window, causal=causal)
-        if head_axis is not None:
-            from jax.sharding import PartitionSpec as _P
-            out = _pin(out, _P(batch_axis, None, head_axis, None))
-        return out
+        return _attend_block(qi, k, v, qpos_i, kpos_i, scale=scale,
+                             window=window, causal=causal)
 
     def body(_, inp):
         qi, i = inp
@@ -170,8 +152,6 @@ def attention(params: dict, x: jax.Array, positions: jax.Array, *,
               kv_override: jax.Array | None = None,
               causal: bool = True,
               compute_dtype=jnp.bfloat16,
-              weight_gather: bool = False,
-              batch_axis: str | None = None,
               chunked_prefill: bool = True,
               impl: str = "xla") -> tuple[jax.Array, dict | None]:
     """Apply GQA attention.
@@ -188,28 +168,10 @@ def attention(params: dict, x: jax.Array, positions: jax.Array, *,
     Returns:
       (out (B, S, d), updated cache or None)
     """
-    q = layers.dense(params["wq"], x, compute_dtype=compute_dtype,
-                     gather_weight=weight_gather)
+    q = layers.dense(params["wq"], x, compute_dtype=compute_dtype)
     kv_src = x if kv_override is None else kv_override
-    k = layers.dense(params["wk"], kv_src, compute_dtype=compute_dtype,
-                     gather_weight=weight_gather)
-    v = layers.dense(params["wv"], kv_src, compute_dtype=compute_dtype,
-                     gather_weight=weight_gather)
-
-    if weight_gather and cache is None and q.shape[1] % 16 == 0:
-        # heads don't divide TP ⇒ parallelize attention over the sequence
-        # instead (sequence sharding on the model axis).  Without this, SPMD
-        # picks contracting-dim (head_dim) parallelism for the score einsum
-        # and all-reduces an O(S·T·H) f32 tensor per layer.  batch_axis
-        # ('data' in serving; None under the train-path vmap where agents
-        # occupy the data axis) must be named explicitly — a None dim in a
-        # constraint FORCES replication (§Perf iteration B1 found serving
-        # batch silently unsharded by the earlier constraint).
-        from jax.sharding import PartitionSpec as _P
-        seq_spec = _P(batch_axis, "model", None, None)
-        q = jax.lax.with_sharding_constraint(q, seq_spec)
-        k = jax.lax.with_sharding_constraint(k, seq_spec)
-        v = jax.lax.with_sharding_constraint(v, seq_spec)
+    k = layers.dense(params["wk"], kv_src, compute_dtype=compute_dtype)
+    v = layers.dense(params["wv"], kv_src, compute_dtype=compute_dtype)
 
     if kv_override is None:
         if rope_kind == "rope":

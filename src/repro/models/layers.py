@@ -72,23 +72,12 @@ def init_dense(key, shape: tuple[int, ...], dtype=jnp.float32,
 
 
 def dense(params: dict, x: jax.Array, contract: int = 1,
-          compute_dtype=None, gather_weight: bool = False) -> jax.Array:
-    """x @ w contracting x's last `contract` dims with w's first `contract`.
-
-    ``gather_weight`` constrains the (casted) weight to full replication —
-    under SPMD this turns a contracting-dim-sharded weight into an
-    all-gather-on-use (ZeRO-style) instead of a partial-sum activation
-    all-reduce.  Used for QKV projections whose head count doesn't divide
-    the tensor-parallel axis (see ArchConfig.attn_weight_gather).
-    """
-    from jax.sharding import PartitionSpec  # local: keep layers jax-light
+          compute_dtype=None) -> jax.Array:
+    """x @ w contracting x's last `contract` dims with w's first `contract`."""
     w = params["w"]
     if compute_dtype is not None:
         w = w.astype(compute_dtype)
         x = x.astype(compute_dtype)
-    if gather_weight:
-        w = jax.lax.with_sharding_constraint(
-            w, PartitionSpec(*([None] * w.ndim)))
     y = jax.lax.dot_general(
         x, w, (((tuple(range(x.ndim - contract, x.ndim))),
                 tuple(range(contract))), ((), ())))

@@ -88,8 +88,6 @@ def mla_attention(params: dict, x: jax.Array, positions: jax.Array, *,
                   rope_theta: float = 10_000.0,
                   window: int = 0,
                   cache: dict | None = None,
-                  tp_axis: str | None = None,
-                  batch_axis: str | None = None,
                   compute_dtype=jnp.bfloat16) -> tuple[jax.Array, dict | None]:
     """MLA forward.  Prefill when cache is None, absorbed decode otherwise."""
     q_nope, q_rope = _project_q(params, x, cfg, num_heads, compute_dtype)
@@ -114,12 +112,8 @@ def mla_attention(params: dict, x: jax.Array, positions: jax.Array, *,
         # then slice back (vdim ≤ qk_dim always holds for DeepSeek configs)
         qk_dim = q.shape[-1]
         v_pad = jnp.pad(v, ((0, 0), (0, 0), (0, 0), (0, qk_dim - v.shape[-1])))
-        head_axis = tp_axis if (tp_axis is not None
-                                and num_heads % 16 == 0) else None
         out = attn._chunked_prefill(q, k, v_pad, positions, positions,
-                                    scale=scale, window=window, causal=True,
-                                    head_axis=head_axis,
-                                    batch_axis=batch_axis)
+                                    scale=scale, window=window, causal=True)
         out = out[..., :cfg.v_head_dim]
         new_cache = None
     else:

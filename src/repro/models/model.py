@@ -1,7 +1,7 @@
 """Public model facade: build any ArchConfig into init / loss / decode fns.
 
-This is the surface the training loop, the FedDec step, the serving path and
-the dry-run all consume — they never touch layer internals.
+This is the surface the training loop, the FedDec step and the serving path
+all consume — they never touch layer internals.
 """
 
 from __future__ import annotations

@@ -72,8 +72,7 @@ def _rank_within_expert(flat_expert: jax.Array, num_experts: int):
 
 def moe_layer(params: dict, x: jax.Array, cfg: MoEConfig, *,
               compute_dtype=jnp.bfloat16,
-              capacity: int | None = None,
-              ep_axis: str | None = None) -> tuple[jax.Array, jax.Array]:
+              capacity: int | None = None) -> tuple[jax.Array, jax.Array]:
     """Apply the MoE block.
 
     Args:
@@ -105,12 +104,6 @@ def moe_layer(params: dict, x: jax.Array, cfg: MoEConfig, *,
     safe_rank = jnp.where(keep, rank, 0)
     contrib = jnp.where(keep[:, None], tok_rep, 0.0)
     buf = buf.at[flat_e, safe_rank].add(contrib, mode="drop")
-    # NOTE on expert parallelism: constraining buf to P(ep_axis, None, None)
-    # here was measured WORSE (§Perf iteration C2, refuted): the scatter
-    # produces a d-sharded buffer and the constraint adds 3×1.1 TB resharding
-    # all-gathers instead of removing the 0.6 TB expert-einsum all-reduce.
-    # The proper fix is a shard_map all-to-all dispatch (iteration C4).
-    del ep_axis
 
     # ---- expert FFN (batched over E; swiglu) -------------------------------
     wi = params["wi"]["w"].astype(compute_dtype)

@@ -167,8 +167,7 @@ def apply_block(params: dict, x: jax.Array, positions: jax.Array,
             y, c = mla_lib.mla_attention(
                 params["attn"], h, positions, num_heads=cfg.num_heads,
                 cfg=cfg.mla, rope_theta=cfg.rope_theta, window=window,
-                cache=self_cache, tp_axis=cfg.tp_axis_name,
-                batch_axis=cfg.batch_axis_name, compute_dtype=cdt)
+                cache=self_cache, compute_dtype=cdt)
         else:
             y, c = attn_lib.attention(
                 params["attn"], h, positions, num_kv_heads=cfg.num_kv_heads,
@@ -176,8 +175,6 @@ def apply_block(params: dict, x: jax.Array, positions: jax.Array,
                 rope_kind=cfg.rope_kind, rope_theta=cfg.rope_theta,
                 mrope_positions=mrope_positions, cache=self_cache,
                 causal=causal, compute_dtype=cdt,
-                weight_gather=cfg.attn_weight_gather,
-                batch_axis=cfg.batch_axis_name,
                 chunked_prefill=cfg.attn_chunked_prefill, impl=impl)
         if c is not None:
             new_cache["self"] = c
@@ -214,8 +211,7 @@ def apply_block(params: dict, x: jax.Array, positions: jax.Array,
     h2 = layers.rms_norm(params["norm2"], x, cfg.norm_eps)
     if "moe" in params:
         y, aux_l = moe_lib.moe_layer(params["moe"], h2, cfg.moe,
-                                     compute_dtype=cdt,
-                                     ep_axis=cfg.tp_axis_name)
+                                     compute_dtype=cdt)
         aux = aux + aux_l
     else:
         y = layers.mlp(params["mlp"], h2, cfg.mlp_kind, compute_dtype=cdt)
@@ -388,15 +384,6 @@ def forward(params: dict, batch: Batch, cfg: ArchConfig, *,
         caches=caches, mrope_positions=batch.get("mrope_positions"),
         enc_out=enc_out, long_variant=long_variant, impl=impl, remat=remat)
     x = layers.rms_norm(params["final_norm"], x, cfg.norm_eps)
-    if cfg.batch_axis_name is not None:
-        # serving: re-pin the residual to batch-sharded/d-replicated before
-        # the unembed — sharding churn from row-parallel attention outputs
-        # otherwise leaves x d-sharded+batch-replicated here, and the head
-        # dot partial-sums a full-batch f32 (B,S,V/16) tensor (67 GB/device
-        # at a 256k vocab; §Perf iteration B4)
-        from jax.sharding import PartitionSpec as _P
-        x = jax.lax.with_sharding_constraint(
-            x, _P(cfg.batch_axis_name, None, None))
     if cfg.tie_embeddings:
         logits = jnp.einsum("bsd,vd->bsv", x,
                             params["embed"]["table"].astype(x.dtype))

@@ -1,4 +1,4 @@
-"""Sharding rules: map every parameter / batch / cache leaf to a PartitionSpec.
+"""Sharding rules: map every parameter leaf to a PartitionSpec.
 
 Two federated layouts (DESIGN §3):
 
@@ -26,10 +26,9 @@ from typing import Any
 
 import jax
 import numpy as np
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import PartitionSpec as P
 
-__all__ = ["MeshAxes", "axes_for_mesh", "param_pspecs", "batch_pspecs",
-           "cache_pspecs", "named_shardings", "n_agents_for"]
+__all__ = ["MeshAxes", "param_pspecs", "n_agents_for"]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -47,14 +46,6 @@ class MeshAxes:
     @property
     def model_size(self) -> int:
         return self.sizes[self.model_axis]
-
-
-def axes_for_mesh(mesh: Mesh) -> MeshAxes:
-    names = mesh.axis_names
-    sizes = dict(zip(names, mesh.devices.shape))
-    if "pod" in names:
-        return MeshAxes(("pod", "data"), "model", sizes)
-    return MeshAxes(("data",), "model", sizes)
 
 
 def n_agents_for(cfg, axes: MeshAxes) -> int:
@@ -240,74 +231,3 @@ def serve_param_pspecs(cfg, params_tree: Any, axes: MeshAxes) -> Any:
         return _assign(shape, prefs, fallback_axes=[fsdp_ax])
 
     return jax.tree_util.tree_map_with_path(rule, params_tree)
-
-
-# ---------------------------------------------------------------------------
-# batch / cache rules
-# ---------------------------------------------------------------------------
-
-
-def batch_pspecs(cfg, batch_tree: Any, axes: MeshAxes, *,
-                 stacked: bool) -> Any:
-    """Specs for training batches ((agents, B, S) leaves) or decode batches
-    ((B, S) leaves)."""
-    _with_sizes(axes)
-    dp = axes.data_axes if len(axes.data_axes) > 1 else axes.data_axes[0]
-
-    def rule(path, leaf):
-        names = [getattr(p, "key", str(p)) for p in path]
-        shape = tuple(leaf.shape)
-        mrope = "mrope_positions" in names
-        if stacked:
-            # all stacked leaves are (A, ...); per-agent batch dim follows
-            # (mrope is (A, 3, B, S) so its batch dim sits one deeper)
-            batch_dim = 2 if mrope else 1
-            if cfg.fed_agent_layout == "sharded":
-                return _assign(shape, [(0, dp)])
-            return _assign(shape, [(batch_dim, dp)])
-        batch_dim = 1 if mrope else 0
-        # decode: batch over data; seq-dim fallback for batch=1 long-context
-        return _assign(shape, [(batch_dim, dp)],
-                       fallback_axes=[dp])
-
-    return jax.tree_util.tree_map_with_path(rule, batch_tree)
-
-
-def cache_pspecs(cfg, cache_tree: Any, axes: MeshAxes) -> Any:
-    """Specs for decode caches.
-
-    Preference: batch over data axes, kv-heads over model; for batch=1
-    long-context the fallback shards the time dim instead (flash-decode
-    style), keeping the 500k cache from replicating 512×.
-    """
-    _with_sizes(axes)
-    model = axes.model_axis
-    dp = axes.data_axes if len(axes.data_axes) > 1 else axes.data_axes[0]
-
-    def rule(path, leaf):
-        names = [getattr(p, "key", str(p)) for p in path]
-        shape = tuple(leaf.shape)
-        lead = 1 if "scan" in names else 0
-        leafname = names[-1]
-        if leafname in ("positions", "index"):
-            return P(*([None] * len(shape)))
-        if leafname in ("k", "v"):          # ([G], B, T, KV, hd)
-            return _assign(shape, [(lead + 0, dp), (lead + 2, model),
-                                   (lead + 1, model), (lead + 1, dp)])
-        if leafname in ("latent", "k_rope"):  # ([G], B, T, rank)
-            return _assign(shape, [(lead + 0, dp), (lead + 2, model),
-                                   (lead + 1, model), (lead + 1, dp)])
-        if leafname == "ssm":               # ([G], B, H, P, N)
-            return _assign(shape, [(lead + 0, dp), (lead + 1, model)])
-        if leafname == "conv":              # ([G], B, K-1, C)
-            return _assign(shape, [(lead + 0, dp), (lead + 2, model)])
-        if leafname == "h":                 # ([G], B, W)
-            return _assign(shape, [(lead + 0, dp), (lead + 1, model)])
-        return P(*([None] * len(shape)))
-
-    return jax.tree_util.tree_map_with_path(rule, cache_tree)
-
-
-def named_shardings(mesh: Mesh, spec_tree: Any) -> Any:
-    return jax.tree.map(lambda s: NamedSharding(mesh, s), spec_tree,
-                        is_leaf=lambda x: isinstance(x, P))

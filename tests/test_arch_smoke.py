@@ -9,9 +9,6 @@ CPU:
   * one decode step with caches — assert shape/finiteness (decoder archs);
   * prefill↔decode agreement on a short sequence (exact for the non-MoE
     archs; MoE uses a high capacity factor to avoid legitimate token drops).
-
-The FULL production configs are exercised only via launch/dryrun.py
-(ShapeDtypeStruct, no allocation), as specified.
 """
 
 import dataclasses

@@ -135,15 +135,6 @@ def test_script_exits_nonzero_without_a_chip():
     assert _no_result(res.stdout)
 
 
-def test_script_imports_no_simulated_pod_tooling():
-    """dryrun.py sets XLA_FLAGS as it is imported."""
-    code = ("import sys, chip_smoke; bad = [m for m in ('repro.launch."
-            "dryrun',) if m in sys.modules]; assert not bad, bad")
-    res = subprocess.run([sys.executable, "-c", code], cwd=ROOT, env=_env(),
-                         capture_output=True, text=True, timeout=300)
-    assert res.returncode == 0, res.stderr[-3000:]
-
-
 def test_script_alone_exits_nonzero(tmp_path):
     shutil.copy(os.path.join(ROOT, "chip_smoke.py"), tmp_path)
     env = _env()

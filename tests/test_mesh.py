@@ -17,7 +17,7 @@ import pytest
 
 from repro.launch import hlo_analysis
 from repro.launch.analysis import mesh2d_cost_model
-from repro.launch.mesh import make_agent_mesh, make_fed_mesh
+from repro.launch.mesh import make_fed_mesh
 
 
 # ---------------------------------------------------------------------------

@@ -22,8 +22,9 @@ import numpy as np
 import pytest
 
 from repro.core import FedDecConfig
+from repro.core import engine as engine_lib
 from repro.core import flat as flat_lib
-from repro.core import sharded, topology as topo
+from repro.core import sharded, sweep as sweep_lib, topology as topo
 from repro.core.mixing import MixingDistribution
 from repro.launch import analysis
 
@@ -129,6 +130,50 @@ def _n_shards_for(agents_per_device: int) -> int:
     return n_shards
 
 
+def _smoke_arch_round(n_runs: int):
+    """(EngineSpec, fused 2-step round, abstract args) of the first
+    sharded-layout smoke arch, one agent per host device: the round comes
+    from ``engine.make_engine_round`` over the model's grad_fn and
+    ``flat.make_flat_spec``; ``n_runs`` > 1 makes a seed lattice."""
+    from repro.configs import ARCH_NAMES, get_config
+    from repro.configs.base import FedConfig
+    from repro.launch.mesh import make_agent_mesh
+    from repro.launch.specs import concrete_batch
+    from repro.launch.steps import build_fed_setup
+    from repro.models import build_model
+    from repro.sharding import MeshAxes
+
+    cfg = next(get_config(a) for a in ARCH_NAMES
+               if get_config(a).fed_agent_layout == "sharded").smoke()
+    model = build_model(cfg)
+    n = len(jax.devices())
+    fcfg, _ = build_fed_setup(
+        cfg, MeshAxes(("data",), "model", {"data": n, "model": 1}),
+        FedConfig(n_agents=n, h=2))
+    shapes = jax.eval_shape(model.init, jax.random.key(0))
+    spec = flat_lib.make_flat_spec(shapes)
+    espec = engine_lib.parse_engine_spec([fcfg] * n_runs, n_shards=n)
+    round_fn = engine_lib.make_engine_round(
+        espec, model.grad_fn(), _lr, flat_spec=spec,
+        mesh=make_agent_mesh(n))
+    if n_runs > 1:
+        state = jax.eval_shape(
+            lambda p: sweep_lib.init_sweep_state(espec.plan(), spec, p),
+            shapes)
+    else:
+        state = jax.eval_shape(
+            lambda p: flat_lib.init_flat_state(spec, p, n), shapes)
+    lead = (2, n_runs) if n_runs > 1 else (2,)
+    batch = jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(lead + x.shape, x.dtype),
+        jax.eval_shape(lambda: concrete_batch(cfg, n, 1, 16,
+                                              jax.random.key(0))))
+    keys = jax.eval_shape(
+        lambda: jax.random.split(jax.random.key(0), n_runs)
+        if n_runs > 1 else jax.random.key(0))
+    return espec, round_fn, (state, batch, keys)
+
+
 @multi_device
 class TestShardedContract:
     def test_sharded_gossip_matches_dense(self):
@@ -150,42 +195,25 @@ class TestShardedContract:
                                        atol=1e-5, err_msg=impl)
 
     def test_build_train_lowerable_sharded(self):
-        """launch/steps.py state_layout='sharded' lowers and compiles a real
-        smoke arch (fused) on a data×model host mesh — the dryrun
-        --state-layout sharded path."""
-        from repro import sharding as shd
-        from repro.configs import ARCH_NAMES, SHAPES, get_config
-        from repro.launch.steps import build_train_lowerable
-        cfg = next(get_config(a) for a in ARCH_NAMES
-                   if get_config(a).fed_agent_layout == "sharded").smoke()
-        n_dev = len(jax.devices())
-        mesh = jax.make_mesh((n_dev, 1), ("data", "model"))
-        axes = shd.axes_for_mesh(mesh)
-        shape = next(s for s in SHAPES.values() if s.kind == "train")
-        low = build_train_lowerable(cfg, shape, axes, mesh=mesh,
-                                    fused_steps=2, state_layout="sharded")
-        assert low.name.endswith(":sharded:fused2")
-        low.lower(mesh).compile()
+        """A real smoke arch's fused round, built from its grad_fn and flat
+        spec as train_loop builds it, lowers and compiles through the
+        sharded engine on the host mesh."""
+        espec, round_fn, args = _smoke_arch_round(n_runs=1)
+        lowered = round_fn.lower(*args)
+        assert lowered.out_info[0].flat.shape == args[0].flat.shape
+        lowered.compile()
         with pytest.raises(ValueError, match="mesh"):
-            build_train_lowerable(cfg, shape, axes, state_layout="sharded")
+            engine_lib.make_engine_round(
+                espec, _grad_fn, _lr,
+                flat_spec=flat_lib.make_flat_spec(jnp.zeros(D)))
 
     def test_build_train_lowerable_sharded_sweep(self):
-        """The composed lowering: sweep_runs × state_layout='sharded' lowers
+        """The composed lowering: a 2-run lattice of the same round lowers
         the whole (R, n_local, D) lattice as ONE shard_map program."""
-        from repro import sharding as shd
-        from repro.configs import ARCH_NAMES, SHAPES, get_config
-        from repro.launch.steps import build_train_lowerable
-        cfg = next(get_config(a) for a in ARCH_NAMES
-                   if get_config(a).fed_agent_layout == "sharded").smoke()
-        n_dev = len(jax.devices())
-        mesh = jax.make_mesh((n_dev, 1), ("data", "model"))
-        axes = shd.axes_for_mesh(mesh)
-        shape = next(s for s in SHAPES.values() if s.kind == "train")
-        low = build_train_lowerable(cfg, shape, axes, mesh=mesh,
-                                    fused_steps=2, state_layout="sharded",
-                                    sweep_runs=2, sweep_axis="seed")
-        assert low.name.endswith(":sharded:fused2:sweep2-seed")
-        low.lower(mesh).compile()
+        _, round_fn, args = _smoke_arch_round(n_runs=2)
+        lowered = round_fn.lower(*args)
+        assert lowered.out_info[0].flat.shape[0] == 2
+        lowered.compile()
 
     def test_state_stays_sharded(self):
         """The carried buffer remains block-sharded across round calls —

@@ -20,7 +20,7 @@ Four tiers, mirroring tests/test_compress.py's layout:
 
 The fused-vs-unfused cost model (analysis.roundfuse_cost_model) and the
 sharded boundary/interior split (sharded.boundary_row_split) are unit
-tested here too — benchmarks/check_regression.py recomputes both.
+tested here too.
 """
 
 import os
